@@ -9,9 +9,10 @@ artifact (BENCH_PR3.json), the PR 4 decode weight-traffic artifact
 the PR 9 static-auditor artifact (BENCH_PR9.json), the PR 10
 self-speculative-decoding artifact (BENCH_PR10.json)
 and the PR 6 tensor-parallel artifact
-(BENCH_PR6.json — run as a subprocess: the emulated mesh needs
-XLA_FLAGS set before jax initialises, which has already happened in
-this process).
+(BENCH_PR6.json — run first, as a subprocess: the emulated mesh needs
+XLA_FLAGS set before jax initialises, and this process must not hold
+the accelerator while the child runs, so it imports jax only after the
+child has exited).
 """
 from __future__ import annotations
 
@@ -21,6 +22,13 @@ import sys
 
 
 def main() -> None:
+    tp = subprocess.run(
+        [sys.executable,
+         os.path.join(os.path.dirname(__file__), "tp_bench.py"),
+         "BENCH_PR6.json"])
+    if tp.returncode != 0:
+        raise SystemExit(tp.returncode)
+
     from benchmarks.analysis_bench import analysis_bench
     from benchmarks.block_bench import block_bench
     from benchmarks.decode_bench import decode_bench
@@ -51,13 +59,6 @@ def main() -> None:
     prefix_cache_bench(emit, json_path="BENCH_PR8.json")
     analysis_bench(emit, json_path="BENCH_PR9.json")
     spec_bench(emit, json_path="BENCH_PR10.json")
-    sys.stdout.flush()
-    tp = subprocess.run(
-        [sys.executable,
-         os.path.join(os.path.dirname(__file__), "tp_bench.py"),
-         "BENCH_PR6.json"])
-    if tp.returncode != 0:
-        raise SystemExit(tp.returncode)
 
 
 if __name__ == "__main__":

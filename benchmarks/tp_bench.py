@@ -1,9 +1,10 @@
 """PR 6 tensor-parallel serving bench: scaling + parity + traffic.
 
-Runs the paged serving engine over emulated host meshes (the process
-forces ``--xla_force_host_platform_device_count=8`` before importing
-jax, so it must run in its own interpreter — ``benchmarks/run.py``
-launches it as a subprocess) and writes ``BENCH_PR6.json``:
+Runs the paged serving engine over emulated host meshes (under
+``JAX_PLATFORMS=cpu`` the process forces
+``--xla_force_host_platform_device_count=8`` before importing jax, so it
+must run in its own interpreter — ``benchmarks/run.py`` launches it as a
+subprocess before touching jax itself) and writes ``BENCH_PR6.json``:
 
   * ``parity``  — greedy token streams at mesh sizes {1, 2, 4} checked
     bit-identical against the single-device engine over the mixed-
@@ -25,7 +26,8 @@ from __future__ import annotations
 import os
 
 _flags = os.environ.get("XLA_FLAGS", "")
-if "host_platform_device_count" not in _flags:
+if (os.environ.get("JAX_PLATFORMS") == "cpu"
+        and "host_platform_device_count" not in _flags):
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
 

@@ -3,14 +3,16 @@ stream (bucketed prefill, block-table decode, page reclamation).
 
   PYTHONPATH=src python examples/serve_lm.py
 """
-from repro.launch import serve as serve_driver
+import sys
+
+from repro.launch import serve as serve_cli
 
 
-def main():
-    serve_driver.main(["--arch", "deepseek-7b", "--smoke",
-                       "--requests", "10", "--slots", "4",
-                       "--max-new", "12", "--page-size", "16"])
+def main() -> int:
+    return serve_cli.main(["--arch", "deepseek-7b", "--smoke",
+                           "--requests", "10", "--slots", "4",
+                           "--max-new", "12", "--page-size", "16"])
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

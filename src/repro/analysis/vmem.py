@@ -27,17 +27,13 @@ from typing import List, Optional, Tuple
 
 from repro.analysis.jaxprs import iter_eqns
 from repro.analysis.report import Diagnostic, PassResult
-from repro.core.rowwise import V5E
-
-PLAN_HEADROOM = 2 * 1024 * 1024      # mirrors plan_matmul's budget
+from repro.core.rowwise import vmem_budget
 
 
-def _block_bytes(block_shape, dtype) -> int:
+def _block_bytes(shape, dtype) -> int:
     size = 1
-    for d in block_shape:
-        # pallas marks grid-mapped (squeezed) dims with a non-int
-        # sentinel; they occupy one element of that axis per step
-        size *= int(d) if isinstance(d, int) else 1
+    for d in shape:
+        size *= int(d)
     return size * dtype.itemsize
 
 
@@ -63,11 +59,13 @@ def kernel_footprints(jaxpr_like) -> List[KernelFootprint]:
         gm = eqn.params["grid_mapping"]
         blocks = list(gm.block_mappings)
         n_in = gm.num_inputs
-        in_b = sum(_block_bytes(bm.block_shape,
-                                bm.array_shape_dtype.dtype)
+        # the block as the kernel sees it in VMEM: squeezed
+        # (grid-mapped) dims are already dropped from its shape
+        in_b = sum(_block_bytes(bm.transformed_block_aval.shape,
+                                bm.transformed_block_aval.dtype)
                    for bm in blocks[:n_in])
-        out_b = sum(_block_bytes(bm.block_shape,
-                                 bm.array_shape_dtype.dtype)
+        out_b = sum(_block_bytes(bm.transformed_block_aval.shape,
+                                 bm.transformed_block_aval.dtype)
                     for bm in blocks[n_in:])
         scratch = 0
         n_scratch = gm.num_scratch_operands
@@ -88,10 +86,8 @@ def kernel_footprints(jaxpr_like) -> List[KernelFootprint]:
 def audit_vmem(jaxpr_like, name: str = "graph", *,
                budget: Optional[int] = None) -> PassResult:
     """RWA401 for every traced kernel whose residency exceeds the
-    modeled budget (default: ``V5E.vmem_bytes`` minus the planner's
-    2 MB headroom)."""
-    budget = budget if budget is not None \
-        else V5E.vmem_bytes - PLAN_HEADROOM
+    modeled budget (default: the planner's own, ``vmem_budget()``)."""
+    budget = budget if budget is not None else vmem_budget()
     result = PassResult(name="vmem")
     for fp in kernel_footprints(jaxpr_like):
         result.checked += 1

@@ -45,6 +45,13 @@ class TPUGeometry:
 
 V5E = TPUGeometry()
 
+
+def vmem_budget(geom: TPUGeometry = V5E) -> int:
+    """Bytes a planned kernel's working set may take: the per-core VMEM
+    less 2 MiB of headroom for semaphores and compiler temporaries. The
+    planner and the static VMEM audit both hold kernels to it."""
+    return geom.vmem_bytes - 2 * 1024 * 1024
+
 # dtype -> minimum (second-to-last, last) tile the TPU packs natively
 _MIN_TILE = {2: (16, 128), 4: (8, 128), 1: (32, 128)}
 
@@ -151,18 +158,31 @@ def plan_matmul(m: int, k: int, n: int, *, dtype_bytes: int = 2,
             need += 2 * bm * bn * rb
         if prologue:
             need += 2 * 2 * bk * 4          # gamma/beta fp32 rows
+            # the norm's fp32 copy of the (bm, bk) row panel, which the
+            # TPU compiler allocates beside the pipelined buffers: it
+            # wanted 16.4 MiB against its 16 MiB limit for a (256, 4096)
+            # x (4096, 512) prologue plan modeled at 13.1 MiB
+            need += bm * bk * 4
         return need
 
     # Choose the K panel: as large as fits the VMEM budget.
-    budget = geom.vmem_bytes - 2 * 1024 * 1024  # headroom for semaphores etc.
+    budget = vmem_budget(geom)
     if k_max is None:
         k_max = 8192
     bk = min(_round_up(k, lane), k_max)
     # A wide-N target can blow the budget on its own; give N back first
     # (down to the default 256) before shrinking the K panel, so the
-    # prologue's full-K requirement survives whenever it can.
+    # prologue's full-K requirement survives whenever it can. While the
+    # K panel is whole, rows cost no HBM traffic (each weight panel is
+    # fetched once) but columns do (the row panel is fetched once per
+    # column tile), so a prologue first gives rows back, down to one
+    # MXU height.
+    while prologue and _need(bm, bk, bn) > budget and bm > geom.mxu[0]:
+        bm = _pick_block(m, bm // 2, sub)
     while _need(bm, bk, bn) > budget and bn > 256:
         bn = _pick_block(n, max(bn // 2, 256), lane)
+    while prologue and _need(bm, bk, bn) > budget and bm > sub:
+        bm = _pick_block(m, bm // 2, sub)
     while True:
         if _need(bm, bk, bn) <= budget or bk <= lane:
             break

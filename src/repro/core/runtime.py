@@ -7,6 +7,9 @@
 
 Selected process-wide (launcher flag) or via context manager in tests.
 
+Also owns where the launchers keep JAX's persistent compilation cache
+(:func:`init_compile_cache`).
+
 Also owns the **pipeline-fusion** switch (PR 2): when on (default), the
 models fuse the pre-norm prologue, multi-head projections and
 residual/gating epilogues into single row-wise kernel launches; when
@@ -17,11 +20,27 @@ traffic for the same weights.
 from __future__ import annotations
 
 import contextlib
+import os
+from pathlib import Path
 
 import jax
 
 _IMPL = "auto"
 _FUSE_PIPELINE = True
+
+# fixed, so that one run finds what the last run compiled: the cache
+# key includes the path
+_CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def init_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; call it before the
+    first compile. ``JAX_COMPILATION_CACHE_DIR``, when set, names the
+    directory and nothing else is set; otherwise the cache lives in
+    ``.jax_cache`` at the root of the checkout. Returns the directory."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(_CHECKOUT_CACHE)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def resolve_impl() -> str:

@@ -253,7 +253,7 @@ def rowwise_matmul_p(x: jnp.ndarray, w: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=scratch, interpret=interpret)
     if not interpret:
-        params["compiler_params"] = pltpu.TPUCompilerParams(
+        params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     fn = pl.pallas_call(

@@ -7,16 +7,21 @@ requests.
 Tensor-parallel serving (``--mesh-shape model=4``) needs the devices to
 exist before jax initialises; on a CPU box export
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` first.
+
+Parameters are drawn in the config's dtype on the default device. The
+exit status is 1 when, without ``--fault-plan``, any request ended
+``failed`` or the engine recovered from an error.
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax
-import jax.numpy as jnp
 
 from repro.configs import get_config, get_reduced
+from repro.core import runtime
 from repro.core.block_traffic import serve_kv_traffic
 from repro.core.types import PagingConfig
 from repro.models import lm
@@ -25,7 +30,8 @@ from repro.serve import placement as placement_mod
 from repro.serve.engine import Engine, Request
 
 
-def main(argv=None):
+def main(argv=None) -> int:
+    runtime.init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepseek-7b")
     ap.add_argument("--smoke", action="store_true")
@@ -109,7 +115,7 @@ def main(argv=None):
     else:
         plan = faults_mod.parse_plan(args.fault_plan)
     key = jax.random.PRNGKey(args.seed)
-    params, _ = lm.init_lm(key, cfg, dtype=jnp.float32)
+    params, _ = lm.init_lm(key, cfg)
     eng = Engine(params, cfg, n_slots=args.slots, max_len=args.max_len,
                  eos_id=-1, temperature=args.temperature,
                  top_k=args.top_k, top_p=args.top_p, seed=args.seed,
@@ -159,7 +165,13 @@ def main(argv=None):
           f"chunk={compiles['chunk']} step={compiles['step']} "
           f"spec={compiles.get('spec', 0)} "
           f"buckets={eng.buckets} prefill_chunk={eng.prefill_chunk}")
+    # with no faults injected, any failure is a real one
+    if not len(plan) and (by_status.get("failed") or eng.errors):
+        print(f"FAILED: {by_status.get('failed', 0)} failed requests, "
+              f"errors={eng.errors}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

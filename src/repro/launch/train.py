@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint import checkpointer as ckpt
 from repro.configs import get_config, get_reduced
-from repro.core import partitioning
+from repro.core import partitioning, runtime
 from repro.data.pipeline import DataConfig, PrefetchIterator, SyntheticLM
 from repro.models import lm
 from repro.optim import adamw
@@ -28,6 +28,7 @@ from repro.train import step as tsl
 
 
 def main(argv=None):
+    runtime.init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepseek-7b")
     ap.add_argument("--smoke", action="store_true",

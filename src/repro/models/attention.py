@@ -22,12 +22,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import runtime
-from repro.core import compat
 from repro.core import partitioning as part
 from repro.core.partitioning import logical_constraint
 from repro.core.types import ModelConfig
 from repro.kernels import ops
 from repro.models import rope as rope_lib
+from repro.models.initializers import normal
 
 DENSE_MAX_SEQ = 2048      # above this, 'ref' impl switches to chunked
 
@@ -59,9 +59,7 @@ def init(key, cfg: ModelConfig, stack: Optional[int], dtype,
     ks = jax.random.split(key, 4)
 
     def w(k, din, dout, scale=1.0):
-        std = scale / math.sqrt(din)
-        return (jax.random.normal(k, lead + (din, dout), jnp.float32)
-                * std).astype(dtype)
+        return normal(k, lead + (din, dout), dtype, scale / math.sqrt(din))
 
     if cross:
         params = {"wq": w(ks[0], d, qo), "wkv": w(ks[1], d, 2 * kvo),
@@ -851,7 +849,7 @@ def _decode_seq_sharded(q, k_new, v_new, cache: KVCache, lengths, *,
 
     out_spec = r(("batch", None, "kv_heads"), mesh,
                  shape=(b, 1, hq * hd))
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, new_spec, new_spec, cache_spec, cache_spec,
                   len_spec),

@@ -24,6 +24,7 @@ from repro.core.types import ModelConfig, Stage
 from repro.kernels import ops
 from repro.models import attention, blocks, mamba2, rope
 from repro.models.attention import KVCache
+from repro.models.initializers import normal
 
 # ----------------------------------------------------------------------
 # Init
@@ -57,8 +58,7 @@ def init_lm(key, cfg: ModelConfig, dtype=None):
     d = cfg.d_model
     vp = padded_vocab(cfg)
     params: Dict[str, Any] = {
-        "embed": (jax.random.normal(ks[0], (vp, d), jnp.float32)
-                  * 0.02).astype(dtype),
+        "embed": normal(ks[0], (vp, d), dtype, 0.02),
     }
     specs: Dict[str, Any] = {"embed": ("vocab", "embed")}
     params["stages"], specs["stages"] = [], []
@@ -72,8 +72,7 @@ def init_lm(key, cfg: ModelConfig, dtype=None):
         params["final_norm"]["b"] = jnp.zeros((d,), dtype)
         specs["final_norm"]["b"] = (None,)
     if not cfg.tie_embeddings:
-        params["lm_head"] = (jax.random.normal(ks[2], (d, vp), jnp.float32)
-                             / math.sqrt(d)).astype(dtype)
+        params["lm_head"] = normal(ks[2], (d, vp), dtype, 1 / math.sqrt(d))
         specs["lm_head"] = ("embed", "vocab")
     if cfg.encdec:
         enc_p, enc_s = [], []

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.core.types import ModelConfig
 from repro.kernels import ops
+from repro.models.initializers import normal
 
 
 class MambaState(NamedTuple):
@@ -44,14 +45,12 @@ def init(key, cfg: ModelConfig, stack: Optional[int], dtype):
     proj_out = 2 * d_in + 2 * s.d_state + n_heads
 
     def w(k, din, dout):
-        return (jax.random.normal(k, lead + (din, dout), jnp.float32)
-                / math.sqrt(din)).astype(dtype)
+        return normal(k, lead + (din, dout), dtype, 1 / math.sqrt(din))
 
     params = {
         "in_proj": w(ks[0], d, proj_out),
         "out_proj": w(ks[1], d_in, d),
-        "conv_w": (jax.random.normal(ks[2], lead + (s.d_conv, conv_dim),
-                                     jnp.float32) * 0.1).astype(dtype),
+        "conv_w": normal(ks[2], lead + (s.d_conv, conv_dim), dtype, 0.1),
         "conv_b": jnp.zeros(lead + (conv_dim,), dtype),
         "A_log": jnp.zeros(lead + (n_heads,), jnp.float32),
         "dt_bias": jnp.zeros(lead + (n_heads,), jnp.float32),

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from repro.core import partitioning as part
 from repro.core.types import GATED_ACTS as GATED, ModelConfig
 from repro.kernels import ops
+from repro.models.initializers import normal
 
 
 def init(key, cfg: ModelConfig, stack: Optional[int], dtype,
@@ -29,8 +30,7 @@ def init(key, cfg: ModelConfig, stack: Optional[int], dtype,
     ks = jax.random.split(key, 3)
 
     def w(k, din, dout):
-        return (jax.random.normal(k, lead + (din, dout), jnp.float32)
-                / math.sqrt(din)).astype(dtype)
+        return normal(k, lead + (din, dout), dtype, 1 / math.sqrt(din))
 
     if cfg.act in GATED:
         params = {"wgi": w(ks[0], d, 2 * f), "wo": w(ks[1], f, d)}
@@ -82,8 +82,7 @@ def init_cmix(key, cfg: ModelConfig, stack: Optional[int], dtype):
     ks = jax.random.split(key, 4)
 
     def w(k, din, dout):
-        return (jax.random.normal(k, lead + (din, dout), jnp.float32)
-                / math.sqrt(din)).astype(dtype)
+        return normal(k, lead + (din, dout), dtype, 1 / math.sqrt(din))
 
     params = {"wk": w(ks[0], d, f), "wv": w(ks[1], f, d),
               "wr": w(ks[2], d, d),

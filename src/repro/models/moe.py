@@ -29,10 +29,10 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import partitioning
-from repro.core import compat
 from repro.core import quant
 from repro.core.types import ModelConfig
 from repro.kernels import ops
+from repro.models.initializers import normal
 
 MODEL_AXIS_FOR_PADDING = 16
 
@@ -52,8 +52,7 @@ def init(key, cfg: ModelConfig, stack: Optional[int], dtype):
     ks = jax.random.split(key, 6)
 
     def w(k, *shape):
-        return (jax.random.normal(k, lead + shape, jnp.float32)
-                / math.sqrt(shape[-2])).astype(dtype)
+        return normal(k, lead + shape, dtype, 1 / math.sqrt(shape[-2]))
 
     params = {
         "router": w(ks[0], d, e),
@@ -71,9 +70,8 @@ def init(key, cfg: ModelConfig, stack: Optional[int], dtype):
         fs = mo.d_ff * mo.n_shared
         params["shared_wi"] = w(ks[4], d, fs)
         params["shared_wg"] = w(ks[5], d, fs)
-        params["shared_wo"] = (jax.random.normal(
-            jax.random.fold_in(key, 7), lead + (fs, d), jnp.float32)
-            / math.sqrt(fs)).astype(dtype)
+        params["shared_wo"] = normal(jax.random.fold_in(key, 7),
+                                     lead + (fs, d), dtype, 1 / math.sqrt(fs))
         params["shared_gate"] = jnp.zeros(lead + (d, 1), dtype)
         specs.update({"shared_wi": llead + ("embed", "ffn"),
                       "shared_wg": llead + ("embed", "ffn"),
@@ -219,7 +217,7 @@ def _apply_ep(params, x, *, cfg: ModelConfig, mesh):
         aux = (mo.n_experts * jnp.sum(f_e * p_e) * mo.router_aux_coef)
         return out.reshape(bl, sl, d).astype(xl.dtype), aux
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, rep, wi_spec, wi_spec, wo_spec,
                   {k: rep for k in shared}),

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.core.types import ModelConfig
 from repro.kernels import ops
+from repro.models.initializers import normal
 
 CHUNK = 16
 CLAMP = 3.5
@@ -48,8 +49,7 @@ def init(key, cfg: ModelConfig, stack: Optional[int], dtype):
     ks = jax.random.split(key, 8)
 
     def w(k, din, dout, scale=1.0):
-        return (jax.random.normal(k, lead + (din, dout), jnp.float32)
-                * scale / math.sqrt(din)).astype(dtype)
+        return normal(k, lead + (din, dout), dtype, scale / math.sqrt(din))
 
     params = {
         "wr": w(ks[0], d, d), "wk": w(ks[1], d, d), "wv": w(ks[2], d, d),
@@ -58,8 +58,7 @@ def init(key, cfg: ModelConfig, stack: Optional[int], dtype):
         "w_lora_a": w(ks[5], d, r.decay_lora, 0.1),
         "w_lora_b": (jnp.zeros(lead + (r.decay_lora, d), jnp.float32)
                      ).astype(dtype),
-        "u": (jax.random.normal(ks[6], lead + (h, r.head_dim), jnp.float32)
-              * 0.1).astype(jnp.float32),
+        "u": normal(ks[6], lead + (h, r.head_dim), jnp.float32, 0.1),
         "mu": (0.5 * jnp.ones(lead + (5, d), jnp.float32)).astype(dtype),
         "ln_g": jnp.ones(lead + (d,), dtype),
         "ln_b": jnp.zeros(lead + (d,), dtype),

@@ -27,11 +27,11 @@ import jax.numpy as jnp
 from repro.configs.swin_t import SwinConfig, ViTConfig
 from repro.core import runtime
 from repro.kernels import ops
+from repro.models.initializers import normal
 
 
 def _w(key, din, dout, dtype):
-    return (jax.random.normal(key, (din, dout), jnp.float32)
-            / math.sqrt(din)).astype(dtype)
+    return normal(key, (din, dout), dtype, 1 / math.sqrt(din))
 
 
 def _window_partition(x, w):
@@ -93,9 +93,9 @@ def init_swin(key, cfg: SwinConfig, dtype=jnp.float32):
                 "mlp1_b": jnp.zeros((int(cfg.mlp_ratio * c),), dtype),
                 "mlp2": _w(next(ks), int(cfg.mlp_ratio * c), c, dtype),
                 "mlp2_b": jnp.zeros((c,), dtype),
-                "rel_bias": (jax.random.normal(
-                    next(ks), ((2 * cfg.window - 1) ** 2, heads),
-                    jnp.float32) * 0.02).astype(dtype),
+                "rel_bias": normal(next(ks),
+                                   ((2 * cfg.window - 1) ** 2, heads),
+                                   dtype, 0.02),
             }
             stage["blocks"].append(blk)
         if si < len(cfg.depths) - 1:
@@ -244,8 +244,7 @@ def init_vit(key, cfg: ViTConfig, dtype=jnp.float32):
                       dtype),
         "patch_b": jnp.zeros((d,), dtype),
         "cls": jnp.zeros((1, 1, d), dtype),
-        "pos": (jax.random.normal(next(ks), (1, tokens + 1, d),
-                                  jnp.float32) * 0.02).astype(dtype),
+        "pos": normal(next(ks), (1, tokens + 1, d), dtype, 0.02),
         "blocks": [],
     }
     for _ in range(cfg.depth):

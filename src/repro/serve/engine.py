@@ -63,7 +63,9 @@ The machinery behind the guarantee:
     exception invalidates it; ``run`` catches step/admit/chunk failures,
     rebuilds device state (fresh paged cache, zeroed host mirrors) and
     replays every live request from its host-side record. A request
-    that keeps failing retires as ``failed`` instead of looping.
+    that keeps failing retires as ``failed`` instead of looping. A
+    program that fails to build (:class:`ProgramError`) is not retried:
+    it propagates out of ``run``.
   * **NaN quarantine** — the decode step computes per-slot finite-ness
     of the logits *inside the jit* (fetched with the sampled tokens in
     the same transfer); a poisoned slot retires as ``failed`` instead of
@@ -97,6 +99,22 @@ from repro.serve.prefix_cache import PrefixCache
 
 TERMINAL_STATUSES = ("ok", "eos", "length", "deadline", "cancelled",
                      "preempted_requeued", "failed")
+
+
+class ProgramError(RuntimeError):
+    """An engine program failed to trace, lower or compile. Replaying
+    the live requests would build the same program and fail the same
+    way, so the recovery boundary lets this propagate."""
+
+
+def _launch(program, *args):
+    """Call a jitted engine program. It traces and compiles on the
+    first call of each signature, and execution errors surface later, at
+    the fetch; so what this call raises is a build failure."""
+    try:
+        return program(*args)
+    except Exception as err:
+        raise ProgramError(f"{type(err).__name__}: {err}") from err
 
 
 @dataclasses.dataclass
@@ -943,7 +961,8 @@ class Engine:
             self.key, sk = jax.random.split(self.key)
             try:
                 first, bad, self.cache, self.lengths, self._last = \
-                    self._admit(
+                    _launch(
+                        self._admit,
                         self.params, self.cache, self.lengths, self._last,
                         jnp.asarray(padded), jnp.int32(slot),
                         jnp.asarray(self.pool.tables[slot]),
@@ -1052,8 +1071,8 @@ class Engine:
             padded = np.zeros((1, shape), np.int32)
             padded[0, :clen] = st.prompt[off:off + clen]
             self.key, sk = jax.random.split(self.key)
-            tok, bad, self.cache, self.lengths, self._last = self._chunk(
-                self.params, self.cache, jnp.asarray(padded),
+            tok, bad, self.cache, self.lengths, self._last = _launch(
+                self._chunk, self.params, self.cache, jnp.asarray(padded),
                 jnp.int32(off), jnp.int32(clen), jnp.int32(slot),
                 jnp.asarray(self.pool.tables[slot]),
                 self.lengths, self._last,
@@ -1326,16 +1345,16 @@ class Engine:
                 if drafts is not None:
                     self._spec_shapes.add(int(drafts[0].shape[1]))
                     nxt, n_acc, bad, self.lengths, self.cache = \
-                        self._spec(
-                            self.params, self.cache,
+                        _launch(
+                            self._spec, self.params, self.cache,
                             jnp.asarray(drafts[0]), self.lengths,
                             self._tables_dev, self._temps,
                             jnp.asarray(active), jnp.asarray(poison),
                             jnp.asarray(dlen), sk)
                     fetch = (nxt, bad, n_acc)
                 else:
-                    nxt, bad, self.lengths, self.cache = self._step(
-                        self.params, self.cache, self._last,
+                    nxt, bad, self.lengths, self.cache = _launch(
+                        self._step, self.params, self.cache, self._last,
                         self.lengths, self._tables_dev, self._temps,
                         jnp.asarray(active), jnp.asarray(poison), sk)
                     self._step_widths.add(int(self._tables_dev.shape[1]))
@@ -1396,6 +1415,8 @@ class Engine:
                         # outside a pool transaction)
                         self.pool.rollback_tail(
                             slot, int(self._host_len[slot]))
+            except ProgramError:
+                raise
             except Exception as err:
                 # recovery boundary: injected StepFault or a real device
                 # error mid-step — the donated cache is presumed lost.

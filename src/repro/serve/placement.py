@@ -8,7 +8,7 @@ later slots in as a third policy — ROADMAP).
 ``SingleDevice`` is the identity policy (exactly the pre-policy engine).
 
 ``TensorParallel`` is Megatron-style TP over a 1-D ``model`` mesh axis,
-run inside ``compat.shard_map`` so the existing model code traces
+run inside ``jax.shard_map`` so the existing model code traces
 unchanged against a *local* config (heads / d_ff divided by the shard
 count):
 
@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import compat, partitioning, quant
+from repro.core import partitioning, quant
 from repro.core.types import GATED_ACTS, ModelConfig
 from repro.models import attention, lm
 from repro.serve.paging import supports_bucketing
@@ -300,8 +300,8 @@ class TensorParallel:
             with partitioning.tp_shard(ax):
                 return fn(*args)
 
-        mapped = compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_vma=False)
+        mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
         # pin output shardings to the exact NamedShardings put_rep /
         # prepare_* commit inputs to: shard_map alone emits equivalent
         # but unequal specs (P(None, None) vs P()), and a fed-back

@@ -117,7 +117,7 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
     the parameter sharding right after AD so the cross-device reduction
     lowers to reduce-scatter instead of a full all-reduce.
     """
-    from repro.core import compat, partitioning
+    from repro.core import partitioning
 
     if not tcfg.compress_pods:
         def train_step(state: TrainState, batch):
@@ -145,7 +145,7 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh=None,
         metric_spec = {k: P() for k in
                        ("loss", "aux_loss", "ntokens", "accuracy")}
         # manual over 'pod' only; data/model stay GSPMD-auto inside
-        fn = compat.shard_map(body, mesh=mesh,
+        fn = jax.shard_map(body, mesh=mesh,
                            in_specs=(rep, rep, batch_spec),
                            out_specs=(rep, rep, metric_spec),
                            axis_names=frozenset({"pod"}),

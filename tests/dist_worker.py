@@ -14,13 +14,14 @@ import numpy as np                                       # noqa: E402
 
 from repro.configs import REDUCED                        # noqa: E402
 from repro.core import partitioning                     # noqa: E402
+from repro.launch.mesh import make_host_mesh             # noqa: E402
 from repro.launch import specs as specs_lib              # noqa: E402
 from repro.models import lm                              # noqa: E402
 from repro.train import step as tsl                      # noqa: E402
 
 
 def _mesh222():
-    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    return make_host_mesh((2, 2, 2), ("pod", "data", "model"))
 
 
 def _setup(arch="deepseek-7b", b=4, s=32):
@@ -127,7 +128,7 @@ def scenario_elastic_restore():
         state_a = jax.device_put(state, sh_a)
     with tempfile.TemporaryDirectory() as d:
         ckpt.save(d, 7, state_a, extra={"data_step": 7})
-        mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_b = make_host_mesh((4, 2), ("data", "model"))
         with partitioning.use_mesh(mesh_b):
             sh_b = partitioning.tree_shardings(mesh_b, specs_tree,
                                                like=state)

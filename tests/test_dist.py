@@ -5,17 +5,9 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 pytestmark = [pytest.mark.slow, pytest.mark.dist]  # subprocess 8-device worlds
-
-# jax 0.4.x shard_map (experimental) rejects inner GSPMD sharding
-# constraints that name a manual axis; the pod-compression step relies
-# on that mix. jax >= 0.5 (top-level jax.shard_map) handles it, but is
-# outside the currently pinned support range — so under the pin this
-# scenario always xfails.
-_OLD_SHARD_MAP = not hasattr(jax, "shard_map")
 
 _WORKER = os.path.join(os.path.dirname(__file__), "dist_worker.py")
 _SCENARIOS = ["fsdp_matches_single", "moe_ep_matches_local",
@@ -25,9 +17,6 @@ _SCENARIOS = ["fsdp_matches_single", "moe_ep_matches_local",
 
 @pytest.mark.parametrize("scenario", _SCENARIOS)
 def test_dist_scenario(scenario):
-    if scenario == "compressed_pods_close" and _OLD_SHARD_MAP:
-        pytest.xfail("jax<0.5 shard_map can't mix a manual 'pod' axis "
-                     "with inner GSPMD constraints naming it")
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

@@ -133,8 +133,11 @@ def test_paged_attention_matches_chunked(chunk):
     out = attention.chunked_attention(q, pool_k, pool_v, causal=False,
                                       window=0, kv_len=lengths,
                                       pages=tables, chunk=chunk)
-    if chunk >= s:       # one online-softmax step each: bit-identical
-        assert bool(jnp.all(out == ref))
+    if chunk >= s:       # one online-softmax step each: the same sums,
+        # but XLA:CPU may fuse the gathered and the dense dot products
+        # with different FMA contraction, so allow a few float32 ulps
+        # at |out| < 2 (seen: 1.5e-7, one ulp)
+        assert float(jnp.max(jnp.abs(out - ref))) < 1e-6
     else:                # different chunking: same math, ulp-level
         assert float(jnp.max(jnp.abs(out - ref))) < 1e-5
 

@@ -6,9 +6,11 @@ import pytest
 from conftest import manual_greedy
 
 from repro.configs import REDUCED
+from repro.core import runtime
+from repro.launch import serve as serve_cli
 from repro.models import lm
 from repro.serve import sampling
-from repro.serve.engine import Engine, Request
+from repro.serve.engine import Engine, ProgramError, Request
 
 pytestmark = pytest.mark.slow  # engine decode loops, ~20s+ on CPU
 
@@ -53,3 +55,34 @@ def test_sampling_modes():
     assert int(s[0]) in (1, 2)
     s = sampling.sample(logits, key, temperature=1.0, top_p=0.5)
     assert int(s[0]) == 1
+
+
+_CLI = ["--arch", "deepseek-7b", "--smoke", "--requests", "3", "--slots", "2",
+        "--max-len", "32", "--page-size", "8", "--max-new", "3"]
+
+
+@pytest.mark.parametrize("step_raises", [False, True])
+def test_serve_cli_exit_status(monkeypatch, step_raises):
+    """Without a fault plan, a run whose steps keep raising ends every
+    request `failed` and the CLI exits 1; a clean run exits 0."""
+    monkeypatch.setattr(runtime, "init_compile_cache", lambda: None)
+    if step_raises:
+        def lost(self):
+            raise RuntimeError("device lost")
+        monkeypatch.setattr(Engine, "_ship_tables", lost)
+    assert serve_cli.main(_CLI) == (1 if step_raises else 0)
+
+
+def test_program_build_failure_is_not_retried(monkeypatch):
+    """A program that fails to trace or compile would fail again on every
+    replay: it leaves the recovery boundary at once."""
+    monkeypatch.setattr(runtime, "init_compile_cache", lambda: None)
+    recovered = []
+    monkeypatch.setattr(Engine, "_recover", lambda self: recovered.append(1))
+
+    def broken(*args, **kwargs):
+        raise TypeError("cannot lower")
+    monkeypatch.setattr(lm, "decode_step", broken)
+    with pytest.raises(ProgramError, match="cannot lower"):
+        serve_cli.main(_CLI)
+    assert not recovered
