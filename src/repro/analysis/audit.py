@@ -23,7 +23,7 @@ so CI can gate merges on it:
 
 ``--mesh-shape 4`` audits the TensorParallel placement; the CLI forces
 the emulated device count into XLA_FLAGS *before* importing jax, so it
-works on a single-CPU box (mirroring benchmarks/tp_bench.py).
+works on a single-CPU box.
 """
 from __future__ import annotations
 
@@ -51,11 +51,11 @@ def _parse_args(argv=None):
 
 
 # sanctioned jax.device_get sites per engine function: THE serving
-# latency contract. run(): the one decode fetch; _fill_slots(): the
+# latency contract. run(): the one decode fetch; _admit_head(): the
 # one-shot prefill's first token; _advance_chunks(): the final chunk's
 # token (intermediate chunks stay async). Everything else in
 # src/repro/serve is allowed zero.
-ENGINE_SYNC_ALLOW = {"run": 1, "_fill_slots": 1, "_advance_chunks": 1}
+ENGINE_SYNC_ALLOW = {"run": 1, "_admit_head": 1, "_advance_chunks": 1}
 
 SERVE_DIR_MODULES = ("engine.py", "paging.py", "sampling.py",
                      "placement.py", "prefix_cache.py", "faults.py",

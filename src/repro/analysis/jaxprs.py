@@ -83,8 +83,7 @@ def weak_type_invars(jaxpr_like) -> List:
 
 # -- weight-sized concatenations (decode hot-path rule) -----------------
 #
-# Migrated from benchmarks/decode_bench.py so tests and the AST rule
-# pass share one definition; the bench re-exports it.
+# One definition, shared by the tests and the AST rule pass.
 
 def weight_concat_eqns(jaxpr_like, min_bytes: int) -> List:
     """Concatenate eqns whose output is at least `min_bytes`: in a

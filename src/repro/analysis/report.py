@@ -6,7 +6,7 @@ Every analysis pass reports through the same two shapes:
     ``RWAnnn`` code, the offending location and a one-line message.
   * :class:`PassResult` — one pass run: its diagnostics plus how many
     invariant sites it actually checked (a pass that checked nothing is
-    suspicious, not clean) and its wall time (BENCH_PR9 reads it).
+    suspicious, not clean) and its wall time.
 
 Code families (catalogued in DESIGN.md §9):
 

@@ -58,7 +58,7 @@ class SyncPolicy:
     ``device_get_allow`` maps function name -> sanctioned number of
     ``jax.device_get`` call sites (unlisted functions get 0). The
     engine profile sanctions exactly one per step-loop phase:
-    ``run`` / ``_fill_slots`` / ``_advance_chunks``.
+    ``run`` / ``_admit_head`` / ``_advance_chunks``.
     """
     device_get_allow: Dict[str, int] = dataclasses.field(
         default_factory=dict)

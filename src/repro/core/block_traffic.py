@@ -17,27 +17,24 @@ regimes the runtime supports:
 
 Both regimes price each matmul with today's fused in-kernel adder tree
 (PR 1) and the real output dtype, so the delta isolates the *inter-op*
-traffic this PR removes. Used by ``benchmarks/block_bench.py`` (the
-BENCH_PR2.json artifact) and the acceptance test.
+traffic this PR removes. Used by ``benchmarks/block_bench.py`` and
+the acceptance test.
 
 The serving-side section models decode-step KV traffic the same way
 for the paged engine (PR 3): dense lockstep caches stream ``n_slots x
 max_len`` rows per layer per step, block-table decode streams only
-each live sequence's pages. Used by ``benchmarks/serve_bench.py``
-(BENCH_PR3.json) and its acceptance test.
+each live sequence's pages. Used by its acceptance test.
 
 The chunked-prefill section (PR 5) prices the serving engine's chunked
 admission: the monolithic-bucket decode stall it removes against the
-prefix-page re-reads resumability costs. Used by
-``benchmarks/serve_bench.py`` (BENCH_PR5.json) and its acceptance test.
+prefix-page re-reads resumability costs. Used by its acceptance
+test.
 
 The decode weight-traffic section prices the PR 4 param-layout
 migration: with wqkv / wgi stored pre-fused the kernels stream the
 panels straight from the param tree; the PR 2 per-call regime instead
 concatenated the sibling weights every call, paying a panel-sized
-write + read on every decode step. Used by
-``benchmarks/decode_bench.py`` (BENCH_PR4.json) and its acceptance
-test.
+write + read on every decode step. Used by its acceptance test.
 """
 from __future__ import annotations
 

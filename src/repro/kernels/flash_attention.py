@@ -192,5 +192,8 @@ def flash_attention_p(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, hd), jnp.float32),       # fp32 accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
+        metadata={"kernel": "flash_attention", "bq": str(bq),
+                  "bk": str(bk), "hd": str(hd)},
     )(*inputs)
     return out.reshape(b, hq, sq_p, hd)[:, :, :sq, :]

@@ -74,12 +74,16 @@ def layernorm_p(x: jnp.ndarray, gamma: jnp.ndarray,
         fn = pl.pallas_call(
             functools.partial(_norm_kernel, eps=eps, kind=kind),
             grid=grid, in_specs=[x_spec, g_spec, g_spec],
-            out_specs=x_spec, out_shape=out_shape, interpret=interpret)
+            out_specs=x_spec, out_shape=out_shape, interpret=interpret,
+            name="layernorm", metadata={"kernel": "layernorm",
+                                        "norm": kind})
         out = fn(x, g2, beta.reshape(1, d))
     else:
         fn = pl.pallas_call(
             functools.partial(_norm_kernel_nobias, eps=eps, kind=kind),
             grid=grid, in_specs=[x_spec, g_spec],
-            out_specs=x_spec, out_shape=out_shape, interpret=interpret)
+            out_specs=x_spec, out_shape=out_shape, interpret=interpret,
+            name="layernorm", metadata={"kernel": "layernorm",
+                                        "norm": kind})
         out = fn(x, g2)
     return out[:m]

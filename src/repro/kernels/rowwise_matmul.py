@@ -256,10 +256,16 @@ def rowwise_matmul_p(x: jnp.ndarray, w: jnp.ndarray, *,
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
 
+    # the name and metadata reach the TPU op's kernel_metadata, so a
+    # device trace can attribute its time to this kernel and shape
     fn = pl.pallas_call(
         functools.partial(_pipeline_kernel, layout=layout,
                           activation=activation, int8=int8_mode,
                           gated=gated, prologue=prologue, eps=eps,
                           k_true=k),
-        in_specs=in_specs, **params)
+        in_specs=in_specs, name="rowwise_matmul",
+        metadata={"kernel": "rowwise_matmul", "m": str(m), "k": str(k),
+                  "n": str(n), "gated": str(gated),
+                  "prologue": str(prologue)},
+        **params)
     return fn(*inputs)[:m, :n]

@@ -94,6 +94,8 @@ def wkv_p(r, k, v, lw, u, *, chunk: int = 16, interpret: bool = False):
                    jax.ShapeDtypeStruct((b * h, p, p), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((p, p), jnp.float32)],
         interpret=interpret,
+        name="wkv",
+        metadata={"kernel": "wkv", "chunk": str(chunk)},
     )(rf, kf, vf, lwf, uf)
     y = y.reshape(b, h, sp, p).transpose(0, 2, 1, 3)[:, :s]
     return y, s_fin.reshape(b, h, p, p)

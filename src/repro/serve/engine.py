@@ -85,6 +85,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import quant
 from repro.core.types import ModelConfig, PagingConfig
@@ -105,16 +106,6 @@ class ProgramError(RuntimeError):
     """An engine program failed to trace, lower or compile. Replaying
     the live requests would build the same program and fail the same
     way, so the recovery boundary lets this propagate."""
-
-
-def _launch(program, *args):
-    """Call a jitted engine program. It traces and compiles on the
-    first call of each signature, and execution errors surface later, at
-    the fetch; so what this call raises is a build failure."""
-    try:
-        return program(*args)
-    except Exception as err:
-        raise ProgramError(f"{type(err).__name__}: {err}") from err
 
 
 @dataclasses.dataclass
@@ -356,7 +347,11 @@ class Engine:
                       # self-speculative decoding (PR 10): steps that
                       # carried drafts, tokens drafted, tokens accepted
                       "spec_steps": 0, "spec_slot_steps": 0,
-                      "spec_drafted": 0, "spec_accepted": 0}
+                      "spec_drafted": 0, "spec_accepted": 0,
+                      # engine programs built (traced, lowered, compiled
+                      # or loaded from the compile cache) and the host
+                      # seconds of the calls that built them
+                      "program_builds": 0, "program_build_s": 0.0}
         self.page_trace: List[tuple] = []   # per-step (unique, mapped)
         self._share_deferred = False
         self.errors: List[str] = []  # reprs of recovered exceptions
@@ -368,8 +363,8 @@ class Engine:
 
         tk, tp = self.top_k, self.top_p    # static: closed over, one jit
 
-        def step_fn(params, cache, tokens, lengths, tables, temps, active,
-                    poison, key):
+        def decode_step(params, cache, tokens, lengths, tables, temps,
+                        active, poison, key):
             logits, cache = lm.decode_step(params, cache, tokens, lengths,
                                            rcfg, pages=tables)
             # fault injection + containment, both traced so the program
@@ -388,8 +383,8 @@ class Engine:
             new_lengths = jnp.where(active, lengths + 1, 0)
             return nxt, bad, new_lengths, cache
 
-        def admit_fn(params, cache, lengths, last, tokens, slot, pages_row,
-                     plen, temp, key):
+        def prefill(params, cache, lengths, last, tokens, slot, pages_row,
+                    plen, temp, key):
             logits, states = lm.prefill_states(params, tokens, rcfg,
                                                last_pos=plen[None])
             cache = lm.insert_prefill(rcfg, cache, states, slot=slot,
@@ -403,9 +398,9 @@ class Engine:
             last = last.at[slot, 0].set(first)
             return first, bad, cache, lengths, last
 
-        def chunk_fn(params, cache, tokens, offset, chunk_len, slot,
-                     pages_row, lengths, last, temp, key, cow_src,
-                     cow_dst):
+        def prefill_chunk(params, cache, tokens, offset, chunk_len, slot,
+                          pages_row, lengths, last, temp, key, cow_src,
+                          cow_dst):
             # copy-on-write seam, folded into the chunk program: before
             # the first chunk that writes into a partially-shared
             # prefix page, the host remaps the table row and passes the
@@ -432,8 +427,8 @@ class Engine:
             last = last.at[slot, 0].set(tok)
             return tok, bad, cache, lengths, last
 
-        def spec_fn(params, cache, tokens, lengths, tables, temps, active,
-                    poison, draft_len, key):
+        def spec_verify(params, cache, tokens, lengths, tables, temps,
+                        active, poison, draft_len, key):
             # Speculative verify (DESIGN.md §10): `tokens` is a
             # (B, 1 + k_pad) panel — the last committed token followed by
             # each slot's padded draft. One chunk-style forward scores
@@ -514,18 +509,18 @@ class Engine:
         # every step. Placement owns the jit: under TP the entry points
         # run in shard_map over the mesh, host operands replicated.
         self._step = self.placement.jit(
-            step_fn, kinds=(PARAMS, CACHE) + (REP,) * 7,
+            decode_step, kinds=(PARAMS, CACHE) + (REP,) * 7,
             out_kinds=(REP, REP, REP, CACHE), donate=(1,))
         self._admit = self.placement.jit(
-            admit_fn, kinds=(PARAMS, CACHE) + (REP,) * 8,
+            prefill, kinds=(PARAMS, CACHE) + (REP,) * 8,
             out_kinds=(REP, REP, CACHE, REP, REP), donate=(1,))
         self._chunk = self.placement.jit(
-            chunk_fn, kinds=(PARAMS, CACHE) + (REP,) * 11,
+            prefill_chunk, kinds=(PARAMS, CACHE) + (REP,) * 11,
             out_kinds=(REP, REP, CACHE, REP, REP), donate=(1,))
         # verify shards exactly like chunk prefill: replicated panel in,
         # head-sharded pool gather/insert, replicated tokens/counts out
         self._spec = self.placement.jit(
-            spec_fn, kinds=(PARAMS, CACHE) + (REP,) * 8,
+            spec_verify, kinds=(PARAMS, CACHE) + (REP,) * 8,
             out_kinds=(REP, REP, REP, REP, CACHE), donate=(1,))
 
     # ------------------------------------------------------------------
@@ -573,6 +568,29 @@ class Engine:
         if self.spec_k:
             counts["spec"] = n(self._spec, len(self._spec_shapes))
         return counts
+
+    def _launch(self, program, signatures: set, sig, *args):
+        """Call a jitted engine program. ``sig`` is the call's host-side
+        signature (padded length, panel shape or table width), and
+        ``signatures`` the set behind :meth:`compile_counts` that holds
+        the ones already built: a new one traces and compiles, so the
+        call is counted in ``stats["program_builds"]``, its host seconds
+        in ``stats["program_build_s"]``, and it runs inside an
+        ``engine.build`` trace span. Execution errors surface later, at
+        the fetch; so what this call raises is a build failure."""
+        try:
+            if sig in signatures:
+                return program(*args)
+            t0 = time.perf_counter()
+            with TraceAnnotation("engine.build", program=program.__name__,
+                                 signature=sig):
+                out = program(*args)
+        except Exception as err:
+            raise ProgramError(f"{type(err).__name__}: {err}") from err
+        signatures.add(sig)
+        self.stats["program_builds"] += 1
+        self.stats["program_build_s"] += time.perf_counter() - t0
+        return out
 
     def audit_entry_points(self):
         """The jitted entry points with representative arguments,
@@ -867,124 +885,136 @@ class Engine:
             if (self.active[slot] is not None or slot in self.chunking
                     or not self.queue):
                 continue
-            pend = self.queue[0]
-            req = pend.req
-            worst = self._worst_case(pend)
-            prompt = self._effective_prompt(pend)
-            plen = int(prompt.shape[0])
-            shared, partial, hit = self._prefix_match(prompt)
-            if self._share_defer(prompt, hit):
-                # an in-flight chunked prefill is building a longer
-                # shared prefix than the tree holds today: admitting now
-                # would recompute its pages into private copies — wait
-                # for the provider instead. run() does not count this
-                # as a blocked head, so the provider is never preempted
-                # to "unblock" the head it is about to serve.
-                self._share_deferred = True
-                self.stats["share_deferrals"] += 1
-                break
-            if not self.pool.can_admit_pages(
-                    self.pool._pages_for(worst)
-                    + (1 if partial is not None else 0)):
-                break                # FIFO: wait for pages, don't skip
-            self.stats["prompt_tokens"] += plen
-            if hit:
-                # prefix-cache hit: map the shared pages (refcount++,
-                # zero prefill FLOPs for those rows) and schedule only
-                # the uncached suffix through the chunk path; a
-                # partially-covered boundary page maps COW-pending (its
-                # private replacement is the +1 page charged above)
-                self.pool.begin()
-                self.pool.admit(slot, worst)
-                self.pool.map_shared(slot, shared)
-                if partial is not None:
-                    self.pool.map_shared(slot, [partial[0]],
-                                         cow_tail=True)
-                self.pool.commit()
-                self.queue.popleft()
-                if pend.admit_t is None:
-                    pend.admit_t = time.perf_counter()
-                self._seq += 1
-                self._admit_seq[slot] = self._seq
-                admitted += 1
-                self.stats["prefix_hits"] += 1
-                self.stats["prefix_hit_tokens"] += hit
-                self.chunking[slot] = _ChunkState(
-                    pend=pend, prompt=prompt,
-                    sched=[(hit + o, c, s) for o, c, s in
-                           chunk_schedule(plen - hit, self.prefill_chunk,
-                                          self.buckets)],
-                    hit=hit, cow=partial is not None)
-                continue
-            if not (self.prefill_chunk and plen > self.prefill_chunk):
-                # one-shot prefill draws the whole prompt inside the
-                # transaction below — evict LRU branches first (never
-                # inside: rollback can't resurrect a dropped node)
-                self._make_room(self.pool._pages_for(plen))
+            with TraceAnnotation("engine.admit", slot=slot,
+                                 rid=self.queue[0].req.rid) as span:
+                if not self._admit_head(slot, span):
+                    break
+            admitted += 1
+        return admitted
+
+    def _admit_head(self, slot: int, span) -> bool:
+        """Admit the queue head into the free ``slot``: map its cached
+        prefix, reserve its pages, and either schedule its chunks or run
+        its one-shot prefill. False when the head must wait (pages, an
+        in-flight shared prefix, an allocation fault): admission stops
+        for this iteration. ``span`` is the caller's ``engine.admit``
+        trace span, which learns the prompt length and the cache hit."""
+        pend = self.queue[0]
+        req = pend.req
+        worst = self._worst_case(pend)
+        prompt = self._effective_prompt(pend)
+        plen = int(prompt.shape[0])
+        shared, partial, hit = self._prefix_match(prompt)
+        span.set_metadata(prompt_len=plen, hit=hit)
+        if self._share_defer(prompt, hit):
+            # an in-flight chunked prefill is building a longer
+            # shared prefix than the tree holds today: admitting now
+            # would recompute its pages into private copies — wait
+            # for the provider instead. run() does not count this
+            # as a blocked head, so the provider is never preempted
+            # to "unblock" the head it is about to serve.
+            self._share_deferred = True
+            self.stats["share_deferrals"] += 1
+            return False
+        if not self.pool.can_admit_pages(
+                self.pool._pages_for(worst)
+                + (1 if partial is not None else 0)):
+            return False         # FIFO: wait for pages, don't skip
+        self.stats["prompt_tokens"] += plen
+        if hit:
+            # prefix-cache hit: map the shared pages (refcount++,
+            # zero prefill FLOPs for those rows) and schedule only
+            # the uncached suffix through the chunk path; a
+            # partially-covered boundary page maps COW-pending (its
+            # private replacement is the +1 page charged above)
             self.pool.begin()
-            try:
-                self.pool.admit(slot, worst)
-                if self.prefill_chunk and plen > self.prefill_chunk:
-                    # chunked prefill: reserve now, run the prompt as
-                    # row panels across engine steps (_advance_chunks) —
-                    # pages are charged per chunk, and admission itself
-                    # costs no forward, so co-resident decode slots
-                    # never stall on the monolithic bucket program
-                    self.pool.commit()
-                    self.queue.popleft()
-                    if pend.admit_t is None:
-                        pend.admit_t = time.perf_counter()
-                    self._seq += 1
-                    self._admit_seq[slot] = self._seq
-                    admitted += 1
-                    self.chunking[slot] = _ChunkState(
-                        pend=pend, prompt=prompt,
-                        sched=chunk_schedule(plen, self.prefill_chunk,
-                                             self.buckets))
-                    continue
-                self.pool.ensure(slot, plen)
-            except AllocFault:
-                self.pool.rollback()
-                self.stats["alloc_faults"] += 1
-                break                # retry the same head next iteration
+            self.pool.admit(slot, worst)
+            self.pool.map_shared(slot, shared)
+            if partial is not None:
+                self.pool.map_shared(slot, [partial[0]],
+                                     cow_tail=True)
             self.pool.commit()
             self.queue.popleft()
             if pend.admit_t is None:
                 pend.admit_t = time.perf_counter()
             self._seq += 1
             self._admit_seq[slot] = self._seq
-            admitted += 1
-            bl = bucket_for(plen, self.buckets) if self.buckets else plen
-            self._prefill_lens.add(bl)
-            padded = np.zeros((1, bl), np.int32)
-            padded[0, :plen] = prompt
-            self.key, sk = jax.random.split(self.key)
-            try:
-                first, bad, self.cache, self.lengths, self._last = \
-                    _launch(
-                        self._admit,
-                        self.params, self.cache, self.lengths, self._last,
-                        jnp.asarray(padded), jnp.int32(slot),
-                        jnp.asarray(self.pool.tables[slot]),
-                        jnp.int32(plen),
-                        jnp.float32(self._req_temp(req)), sk)
+            self.stats["prefix_hits"] += 1
+            self.stats["prefix_hit_tokens"] += hit
+            self.chunking[slot] = _ChunkState(
+                pend=pend, prompt=prompt,
+                sched=[(hit + o, c, s) for o, c, s in
+                       chunk_schedule(plen - hit, self.prefill_chunk,
+                                      self.buckets)],
+                hit=hit, cow=partial is not None)
+            return True
+        if not (self.prefill_chunk and plen > self.prefill_chunk):
+            # one-shot prefill draws the whole prompt inside the
+            # transaction below — evict LRU branches first (never
+            # inside: rollback can't resurrect a dropped node)
+            self._make_room(self.pool._pages_for(plen))
+        self.pool.begin()
+        try:
+            self.pool.admit(slot, worst)
+            if self.prefill_chunk and plen > self.prefill_chunk:
+                # chunked prefill: reserve now, run the prompt as
+                # row panels across engine steps (_advance_chunks) —
+                # pages are charged per chunk, and admission itself
+                # costs no forward, so co-resident decode slots
+                # never stall on the monolithic bucket program
+                self.pool.commit()
+                self.queue.popleft()
+                if pend.admit_t is None:
+                    pend.admit_t = time.perf_counter()
+                self._seq += 1
+                self._admit_seq[slot] = self._seq
+                self.chunking[slot] = _ChunkState(
+                    pend=pend, prompt=prompt,
+                    sched=chunk_schedule(plen, self.prefill_chunk,
+                                         self.buckets))
+                return True
+            self.pool.ensure(slot, plen)
+        except AllocFault:
+            self.pool.rollback()
+            self.stats["alloc_faults"] += 1
+            return False         # retry the same head next iteration
+        self.pool.commit()
+        self.queue.popleft()
+        if pend.admit_t is None:
+            pend.admit_t = time.perf_counter()
+        self._seq += 1
+        self._admit_seq[slot] = self._seq
+        bl = bucket_for(plen, self.buckets) if self.buckets else plen
+        padded = np.zeros((1, bl), np.int32)
+        padded[0, :plen] = prompt
+        self.key, sk = jax.random.split(self.key)
+        try:
+            first, bad, self.cache, self.lengths, self._last = \
+                self._launch(
+                    self._admit, self._prefill_lens, bl,
+                    self.params, self.cache, self.lengths, self._last,
+                    jnp.asarray(padded), jnp.int32(slot),
+                    jnp.asarray(self.pool.tables[slot]),
+                    jnp.int32(plen),
+                    jnp.float32(self._req_temp(req)), sk)
+            with TraceAnnotation("engine.admit.fetch"):
                 first, bad = jax.device_get((first, bad))
-            except Exception:
-                # the admit program itself died: restore the pool and
-                # the queue head before the recovery boundary takes over,
-                # so the rid is never lost and no pages leak
-                self.pool.release(slot)
-                self.queue.appendleft(pend)
-                raise
-            if bad:
-                # non-finite prefill logits: quarantine before the slot
-                # ever joins the lockstep batch
-                self.pool.release(slot)
-                self.stats["nan_quarantined"] += 1
-                self._finish(pend, list(pend.prior), "failed")
-                continue
-            self._activate(slot, pend, int(first))
-        return admitted
+        except Exception:
+            # the admit program itself died: restore the pool and
+            # the queue head before the recovery boundary takes over,
+            # so the rid is never lost and no pages leak
+            self.pool.release(slot)
+            self.queue.appendleft(pend)
+            raise
+        if bad:
+            # non-finite prefill logits: quarantine before the slot
+            # ever joins the lockstep batch
+            self.pool.release(slot)
+            self.stats["nan_quarantined"] += 1
+            self._finish(pend, list(pend.prior), "failed")
+            return True
+        self._activate(slot, pend, int(first))
+        return True
 
     def _activate(self, slot, pend: _Pending, first: int):
         """A slot's prefill (one-shot or final chunk) produced its first
@@ -1067,17 +1097,22 @@ class Engine:
                     pg = int(self.pool.tables[slot, lp])
                     assert self.pool.refs[pg] == 1, (
                         f"chunk would scatter into shared page {pg}")
-            self._chunk_shapes.add(shape)
             padded = np.zeros((1, shape), np.int32)
             padded[0, :clen] = st.prompt[off:off + clen]
             self.key, sk = jax.random.split(self.key)
-            tok, bad, self.cache, self.lengths, self._last = _launch(
-                self._chunk, self.params, self.cache, jnp.asarray(padded),
-                jnp.int32(off), jnp.int32(clen), jnp.int32(slot),
-                jnp.asarray(self.pool.tables[slot]),
-                self.lengths, self._last,
-                jnp.float32(self._req_temp(st.pend.req)), sk,
-                jnp.int32(cow_src), jnp.int32(cow_dst))
+            with TraceAnnotation("engine.chunks.launch",
+                                 rid=st.pend.req.rid, offset=off,
+                                 len=clen):
+                tok, bad, self.cache, self.lengths, self._last = \
+                    self._launch(
+                        self._chunk, self._chunk_shapes, shape,
+                        self.params, self.cache,
+                        jnp.asarray(padded), jnp.int32(off),
+                        jnp.int32(clen), jnp.int32(slot),
+                        jnp.asarray(self.pool.tables[slot]),
+                        self.lengths, self._last,
+                        jnp.float32(self._req_temp(st.pend.req)), sk,
+                        jnp.int32(cow_src), jnp.int32(cow_dst))
             spent += shape
             st.sched.pop(0)
             advanced += 1
@@ -1086,7 +1121,9 @@ class Engine:
                 # fetches — intermediate chunks stay fully async (a NaN
                 # they wrote reaches this chunk's logits via the prefix
                 # gather, so one flag covers the whole prefill)
-                tok, bad = jax.device_get((tok, bad))
+                with TraceAnnotation("engine.chunks.fetch",
+                                     rid=st.pend.req.rid):
+                    tok, bad = jax.device_get((tok, bad))
                 del self.chunking[slot]
                 if bad:
                     self.pool.release(slot)
@@ -1254,6 +1291,105 @@ class Engine:
 
     # -- the loop -------------------------------------------------------
 
+    def _prepare_decode(self, active: np.ndarray, clock: int):
+        """Host side of a decode step before its launch: drafts, the
+        step's page draws (one transaction), the block tables, the
+        injected poison and the step's key. None when a page draw
+        failed: the whole step retries next iteration."""
+        drafts = self._build_drafts(active)
+        dlen = (drafts[1] if drafts is not None
+                else np.zeros((self.n_slots,), np.int32))
+        # rows this step may write: the decode position, plus —
+        # speculating — the slot's full draft tail (rejected
+        # tail pages roll back after the accepted counts land)
+        need = {int(s): int(self._host_len[s]) + 1 + int(dlen[s])
+                for s in np.flatnonzero(active)}
+        self._make_room(sum(
+            max(0, self.pool._pages_for(n)
+                - int(self.pool.n_alloc[s]))
+            for s, n in need.items()))
+        self.pool.begin()
+        try:
+            for s, n in need.items():
+                self.pool.ensure(s, n)      # lazy tail draws
+        except AllocFault:
+            self.pool.rollback()
+            self.stats["alloc_faults"] += 1
+            return None
+        self.pool.commit()
+        if self.prefix_cache is not None:
+            for s, n in need.items():
+                for lp in range(int(self._host_len[s]) // self.page_size,
+                                (n - 1) // self.page_size + 1):
+                    pg = int(self.pool.tables[s, lp])
+                    assert self.pool.refs[pg] == 1, (
+                        f"decode write aimed at shared page {pg}")
+        self._ship_tables()
+        poison = np.zeros((self.n_slots,), bool)
+        pslots = self.faults.poison_slots(clock)
+        if pslots:
+            for s in pslots:
+                if s is None:
+                    poison |= active
+                else:
+                    poison[s] = True
+        if self.faults.step_raises(clock):
+            raise StepFault(f"injected step exception @clock {clock}")
+        self.key, sk = jax.random.split(self.key)
+        return drafts, dlen, poison, sk
+
+    def _emit(self, active: np.ndarray, drafts, got):
+        """Host bookkeeping after the decode step's fetch: lengths,
+        emitted tokens, retirements and quarantines, and the rejected
+        draft tails' pages."""
+        nxt_host, bad_host = got[0], got[1]
+        acc_host = (np.asarray(got[2], np.int64) if len(got) > 2
+                    else np.zeros((self.n_slots,), np.int64))
+        now = time.perf_counter()
+        if drafts is not None:
+            dlen = drafts[1]
+            self.stats["spec_steps"] += 1
+            self.stats["spec_slot_steps"] += int(active.sum())
+            self.stats["spec_drafted"] += int(dlen[active].sum())
+            self.stats["spec_accepted"] += int(acc_host[active].sum())
+        self._host_len[active] += 1 + acc_host[active]
+        self._host_len[~active] = 0
+        self.kv_trace.append(
+            [int(self._host_len[s]) for s in np.flatnonzero(active)])
+        for slot in np.flatnonzero(active):
+            slot = int(slot)
+            pend = self.active[slot]
+            if bad_host[slot]:
+                # quarantine: this slot's logits went non-finite;
+                # retire it alone, the lockstep batch moves on
+                self.stats["nan_quarantined"] += 1
+                self._retire(slot, "failed")
+                continue
+            emitted = [int(nxt_host[slot])]
+            if drafts is not None:
+                # accepted draft prefix first, then the verify
+                # step's own replacement/bonus token
+                emitted = [int(t) for t in drafts[0][
+                    slot, 1:1 + int(acc_host[slot])]] + emitted
+            for tok in emitted:
+                self.out_tokens[slot].append(tok)
+                self._token_times[slot].append(now)
+                if tok == self.eos_id:
+                    self._retire(slot, "eos")
+                    break
+                if len(self.out_tokens[slot]) >= pend.req.max_new:
+                    self._retire(slot, "ok")
+                    break
+            if self.active[slot] is None:
+                continue     # retired mid-emission: pages freed
+            if int(self._host_len[slot]) >= self.max_len - 1:
+                self._retire(slot, "length")
+            elif drafts is not None:
+                # return the rejected draft tail's pages; the
+                # reservation survives (rollback_tail is legal
+                # outside a pool transaction)
+                self.pool.rollback_tail(slot, int(self._host_len[slot]))
+
     def run(self, max_steps: int = 10_000) -> List[Completion]:
         """Continuous-batching loop until queue + slots drain. One
         iteration = deadline sweep + admissions (preempting if a
@@ -1261,7 +1397,18 @@ class Engine:
         one lockstep decode step. Hitting `max_steps` does NOT drop
         work: everything outstanding terminal-completes as
         `preempted_requeued` (tokens so far attached) and the engine
-        stays serviceable."""
+        stays serviceable.
+
+        Each iteration is an ``engine.iteration`` trace span (args
+        ``clock``, ``n_active``) holding one span per phase:
+        ``engine.sweep``, ``engine.admit`` per admission attempt (with
+        ``engine.admit.fetch``), ``engine.chunks`` (with
+        ``engine.chunks.launch`` per chunk and ``engine.chunks.fetch``),
+        ``engine.decode.prepare``, ``.launch``, ``.fetch`` and ``.emit``,
+        and ``engine.recover``; a call that builds a program runs in an
+        ``engine.build`` span. They land in a ``jax.profiler`` trace
+        when one is recording, and cost about a microsecond each when
+        none is."""
         steps = 0
         recoveries = 0
         self.kv_trace = []           # fresh trace per run (bounded host mem)
@@ -1274,161 +1421,92 @@ class Engine:
             steps += 1
             clock = self.clock
             self.clock += 1
-            if self.faults.alloc_fails(clock):
-                self._arm_alloc_fault(clock)
-            slow = self.faults.slow_s(clock)
-            if slow:
-                time.sleep(slow)
-            try:
-                self._sweep_deadlines()
-                admitted = self._fill_slots()
-                if self.queue and admitted == 0:
-                    # a share-deferred head is *waiting on* a resident
-                    # prefill, not starved by it: counting it as blocked
-                    # could preempt the very slot about to serve it
-                    if not self._share_deferred:
-                        self._head_blocked += 1
-                        if self._maybe_preempt():
-                            admitted += self._fill_slots()
-                else:
-                    self._head_blocked = 0
-                self._advance_chunks()
-                self.page_trace.append((self.pool.unique_live(),
-                                        self.pool.live_pages()))
-                active = np.asarray([a is not None for a in self.active])
-                if not active.any():
-                    if self.queue or self.chunking:
-                        continue     # blocked or mid-prefill: next tick
-                    break            # everything admitted retired at once
-                drafts = self._build_drafts(active)
-                dlen = (drafts[1] if drafts is not None
-                        else np.zeros((self.n_slots,), np.int32))
-                # rows this step may write: the decode position, plus —
-                # speculating — the slot's full draft tail (rejected
-                # tail pages roll back after the accepted counts land)
-                need = {int(s): int(self._host_len[s]) + 1 + int(dlen[s])
-                        for s in np.flatnonzero(active)}
-                self._make_room(sum(
-                    max(0, self.pool._pages_for(n)
-                        - int(self.pool.n_alloc[s]))
-                    for s, n in need.items()))
-                self.pool.begin()
+            with TraceAnnotation("engine.iteration", clock=clock,
+                                 n_active=self.n_slots
+                                 - self.active.count(None)):
+                if self.faults.alloc_fails(clock):
+                    self._arm_alloc_fault(clock)
+                slow = self.faults.slow_s(clock)
+                if slow:
+                    time.sleep(slow)
                 try:
-                    for s, n in need.items():
-                        self.pool.ensure(s, n)      # lazy tail draws
-                except AllocFault:
-                    self.pool.rollback()
-                    self.stats["alloc_faults"] += 1
-                    continue         # whole step retries next iteration
-                self.pool.commit()
-                if self.prefix_cache is not None:
-                    for s, n in need.items():
-                        for lp in range(
-                                int(self._host_len[s]) // self.page_size,
-                                (n - 1) // self.page_size + 1):
-                            pg = int(self.pool.tables[s, lp])
-                            assert self.pool.refs[pg] == 1, (
-                                f"decode write aimed at shared page {pg}")
-                self._ship_tables()
-                poison = np.zeros((self.n_slots,), bool)
-                pslots = self.faults.poison_slots(clock)
-                if pslots:
-                    for s in pslots:
-                        if s is None:
-                            poison |= active
+                    with TraceAnnotation("engine.sweep"):
+                        self._sweep_deadlines()
+                    admitted = self._fill_slots()
+                    if self.queue and admitted == 0:
+                        # a share-deferred head is *waiting on* a
+                        # resident prefill, not starved by it: counting
+                        # it as blocked could preempt the very slot
+                        # about to serve it
+                        if not self._share_deferred:
+                            self._head_blocked += 1
+                            if self._maybe_preempt():
+                                admitted += self._fill_slots()
+                    else:
+                        self._head_blocked = 0
+                    with TraceAnnotation("engine.chunks"):
+                        self._advance_chunks()
+                    self.page_trace.append((self.pool.unique_live(),
+                                            self.pool.live_pages()))
+                    active = np.asarray([a is not None
+                                         for a in self.active])
+                    if not active.any():
+                        if self.queue or self.chunking:
+                            continue     # blocked or mid-prefill
+                        break            # everything retired at once
+                    with TraceAnnotation("engine.decode.prepare"):
+                        prep = self._prepare_decode(active, clock)
+                    if prep is None:
+                        continue     # whole step retries next iteration
+                    drafts, dlen, poison, sk = prep
+                    with TraceAnnotation("engine.decode.launch"):
+                        if drafts is not None:
+                            nxt, n_acc, bad, self.lengths, self.cache = \
+                                self._launch(
+                                    self._spec, self._spec_shapes,
+                                    int(drafts[0].shape[1]),
+                                    self.params, self.cache,
+                                    jnp.asarray(drafts[0]), self.lengths,
+                                    self._tables_dev, self._temps,
+                                    jnp.asarray(active),
+                                    jnp.asarray(poison),
+                                    jnp.asarray(dlen), sk)
+                            fetch = (nxt, bad, n_acc)
                         else:
-                            poison[s] = True
-                if self.faults.step_raises(clock):
-                    raise StepFault(
-                        f"injected step exception @clock {clock}")
-                self.key, sk = jax.random.split(self.key)
-                if drafts is not None:
-                    self._spec_shapes.add(int(drafts[0].shape[1]))
-                    nxt, n_acc, bad, self.lengths, self.cache = \
-                        _launch(
-                            self._spec, self.params, self.cache,
-                            jnp.asarray(drafts[0]), self.lengths,
-                            self._tables_dev, self._temps,
-                            jnp.asarray(active), jnp.asarray(poison),
-                            jnp.asarray(dlen), sk)
-                    fetch = (nxt, bad, n_acc)
-                else:
-                    nxt, bad, self.lengths, self.cache = _launch(
-                        self._step, self.params, self.cache, self._last,
-                        self.lengths, self._tables_dev, self._temps,
-                        jnp.asarray(active), jnp.asarray(poison), sk)
-                    self._step_widths.add(int(self._tables_dev.shape[1]))
-                    fetch = (nxt, bad)
-                self._last = nxt[:, None]
-                self._stepped = True
-                # the step's ONE device fetch (tokens + NaN flags — and,
-                # on a speculative step, per-slot accepted counts — in
-                # one transfer)
-                got = jax.device_get(fetch)
-                nxt_host, bad_host = got[0], got[1]
-                acc_host = (np.asarray(got[2], np.int64) if len(got) > 2
-                            else np.zeros((self.n_slots,), np.int64))
-                now = time.perf_counter()
-                if drafts is not None:
-                    self.stats["spec_steps"] += 1
-                    self.stats["spec_slot_steps"] += int(active.sum())
-                    self.stats["spec_drafted"] += int(dlen[active].sum())
-                    self.stats["spec_accepted"] += int(
-                        acc_host[active].sum())
-                self._host_len[active] += 1 + acc_host[active]
-                self._host_len[~active] = 0
-                self.kv_trace.append(
-                    [int(self._host_len[s])
-                     for s in np.flatnonzero(active)])
-                for slot in np.flatnonzero(active):
-                    slot = int(slot)
-                    pend = self.active[slot]
-                    if bad_host[slot]:
-                        # quarantine: this slot's logits went non-finite;
-                        # retire it alone, the lockstep batch moves on
-                        self.stats["nan_quarantined"] += 1
-                        self._retire(slot, "failed")
-                        continue
-                    emitted = [int(nxt_host[slot])]
-                    if drafts is not None:
-                        # accepted draft prefix first, then the verify
-                        # step's own replacement/bonus token
-                        emitted = [int(t) for t in drafts[0][
-                            slot, 1:1 + int(acc_host[slot])]] + emitted
-                    for tok in emitted:
-                        self.out_tokens[slot].append(tok)
-                        self._token_times[slot].append(now)
-                        if tok == self.eos_id:
-                            self._retire(slot, "eos")
-                            break
-                        if len(self.out_tokens[slot]) >= \
-                                pend.req.max_new:
-                            self._retire(slot, "ok")
-                            break
-                    if self.active[slot] is None:
-                        continue     # retired mid-emission: pages freed
-                    if int(self._host_len[slot]) >= self.max_len - 1:
-                        self._retire(slot, "length")
-                    elif drafts is not None:
-                        # return the rejected draft tail's pages; the
-                        # reservation survives (rollback_tail is legal
-                        # outside a pool transaction)
-                        self.pool.rollback_tail(
-                            slot, int(self._host_len[slot]))
-            except ProgramError:
-                raise
-            except Exception as err:
-                # recovery boundary: injected StepFault or a real device
-                # error mid-step — the donated cache is presumed lost.
-                # (AllocFault is handled transactionally at its draw
-                # sites above and never reaches here.)
-                self.errors.append(repr(err))
-                self.stats["recoveries"] += 1
-                recoveries += 1
-                if recoveries > self.max_recoveries:
-                    self._flush_outstanding("failed")
-                    break
-                self._recover()
-            finally:
-                self.pool.alloc_hook = None
+                            nxt, bad, self.lengths, self.cache = \
+                                self._launch(
+                                    self._step, self._step_widths,
+                                    int(self._tables_dev.shape[1]),
+                                    self.params, self.cache, self._last,
+                                    self.lengths, self._tables_dev,
+                                    self._temps, jnp.asarray(active),
+                                    jnp.asarray(poison), sk)
+                            fetch = (nxt, bad)
+                        self._last = nxt[:, None]
+                        self._stepped = True
+                    # the step's ONE device fetch (tokens + NaN flags —
+                    # and, on a speculative step, per-slot accepted
+                    # counts — in one transfer)
+                    with TraceAnnotation("engine.decode.fetch"):
+                        got = jax.device_get(fetch)
+                    with TraceAnnotation("engine.decode.emit"):
+                        self._emit(active, drafts, got)
+                except ProgramError:
+                    raise
+                except Exception as err:
+                    # recovery boundary: injected StepFault or a real
+                    # device error mid-step — the donated cache is
+                    # presumed lost. (AllocFault is handled
+                    # transactionally at its draw sites and never
+                    # reaches here.)
+                    self.errors.append(repr(err))
+                    self.stats["recoveries"] += 1
+                    recoveries += 1
+                    if recoveries > self.max_recoveries:
+                        self._flush_outstanding("failed")
+                        break
+                    with TraceAnnotation("engine.recover"):
+                        self._recover()
+                finally:
+                    self.pool.alloc_hook = None
         return self.completed

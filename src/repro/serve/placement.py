@@ -43,6 +43,7 @@ scale row identically and row-sharded projections replicate it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence
 
 import jax
@@ -296,6 +297,9 @@ class TensorParallel:
         out_specs = tuple(expand(k) for k in out_kinds)
         ax = self.axis
 
+        # the program keeps the entry point's name under TP too, so
+        # the trace reads jit_decode_step and not jit_body
+        @functools.wraps(fn)
         def body(*args):
             with partitioning.tp_shard(ax):
                 return fn(*args)

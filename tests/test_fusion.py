@@ -235,7 +235,7 @@ def test_sublayer_pair_pallas_call_budget():
     """Fused attn+MLP sublayer pair: <= 4 dense-pipeline launches
     ([norm+qkv], [wo+res], [norm+gate|up], [wo+res]) plus the
     attention-core kernel — down from ~9 per-op launches. The counting
-    harness is shared with the BENCH_PR2.json artifact."""
+    harness is shared with benchmarks/block_bench.py."""
     from benchmarks.block_bench import sublayer_pallas_calls
     fused = sublayer_pallas_calls(True)
     unfused = sublayer_pallas_calls(False)

@@ -194,3 +194,26 @@ def test_tp_parity_int8_weights():
     ref, _ = _greedy_streams(qparams, cfg, SingleDevice())
     got, _ = _greedy_streams(qparams, cfg, TensorParallel(4))
     assert got == ref
+
+
+@pytest.mark.slow
+@pytest.mark.dist
+@pytest.mark.parametrize("t", [1, 4])
+def test_tp_programs_keep_their_names(t):
+    """Under TP the shard_map body takes the entry point's name, so the
+    programs lower as ``jit_decode_step``, ``jit_prefill`` and
+    ``jit_prefill_chunk``, as they do on one device."""
+    _need_devices(t)
+    cfg = REDUCED["deepseek-7b"]()
+    params, _ = lm.init_lm(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
+    eng = Engine(params, cfg, n_slots=2, max_len=64, eos_id=-1,
+                 paging=PagingConfig(prefill_chunk=16),
+                 placement=TensorParallel(t))
+    names = {"step": "decode_step", "prefill": "prefill",
+             "chunk": "prefill_chunk"}
+    entries = eng.audit_entry_points()
+    assert sorted(e[0] for e in entries) == sorted(names)
+    for entry, fn, args, _ in entries:
+        assert fn.__name__ == names[entry]
+        assert fn.lower(*args).as_text().startswith(
+            f"module @jit_{names[entry]} ")
