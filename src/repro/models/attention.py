@@ -110,8 +110,10 @@ def cache_specs(window: int = 0):
 
 
 class PagedKVCache(NamedTuple):
-    """Per-layer paged KV pool. k/v: (n_pages + n_slots, page_size,
-    Hkv, hd).
+    """Paged KV pools. k/v: (R, n_pages + n_slots, page_size, Hkv, hd),
+    one pool per scan-stacked layer. The layer scan carries the stacked
+    leaves whole and every pool function addresses its layer with a
+    (traced) ``layer`` index.
 
     Physical pages are shared by every slot in the serving batch; the
     logical order of a slot's tokens lives in the engine's block table
@@ -216,16 +218,17 @@ def _chunked_fwd(q, k, v, limit, *, causal, window, q_offset, chunk):
     return out.reshape(b, hq, sq, hd).astype(q.dtype), lse
 
 
-def _paged_fwd(q, k_pool, v_pool, pages, limit, *, chunk, q_offset=None,
-               window: int = 0):
+def _paged_fwd(q, k_pool, v_pool, pages, limit, *, layer, chunk,
+               q_offset=None, window: int = 0):
     """Online-softmax over a paged KV pool — the same row-wise LSE math
     as :func:`_chunked_fwd`, but each scan chunk *gathers* its KV rows
     from the pool through the block table instead of slicing a dense
     per-slot cache, so only a slot's live pages ever stream.
 
-    q: (B,Hq,Sq,hd); k_pool/v_pool: (n_pages, page_size, Hkv, hd);
-    pages: (B, n_logical_pages) int32 block table; limit: (B,) valid
-    token counts (logical positions >= limit are masked out).
+    q: (B,Hq,Sq,hd); k_pool/v_pool: stacked (R, n_pages, page_size,
+    Hkv, hd) pools, read at ``layer``; pages: (B, n_logical_pages) int32
+    block table; limit: (B,) valid token counts (logical positions >=
+    limit are masked out).
 
     ``q_offset`` ((B,) int32) turns the single-position decode gather
     into a multi-query *prefix* gather for chunked prefill: query row i
@@ -240,7 +243,7 @@ def _paged_fwd(q, k_pool, v_pool, pages, limit, *, chunk, q_offset=None,
     Returns (out (B,Hq,Sq,hd), lse (B,Hkv,g,Sq) fp32).
     """
     b, hq, sq, hd = q.shape
-    _, ps, hkv, _ = k_pool.shape
+    _, _, ps, hkv, _ = k_pool.shape
     g = hq // hkv
     n_log = pages.shape[1]
     ppc = max(1, min(n_log, chunk // ps))      # pages gathered per chunk
@@ -256,8 +259,8 @@ def _paged_fwd(q, k_pool, v_pool, pages, limit, *, chunk, q_offset=None,
 
     def step(carry, inp):
         pid, base = inp                                        # (B,ppc)
-        kb = jnp.take(k_pool, pid, axis=0)   # (B, ppc, ps, Hkv, hd)
-        vb = jnp.take(v_pool, pid, axis=0)
+        kb = k_pool[layer, pid]              # (B, ppc, ps, Hkv, hd)
+        vb = v_pool[layer, pid]
         kb = kb.reshape(b, ppc * ps, hkv, hd).transpose(0, 2, 1, 3)
         vb = vb.reshape(b, ppc * ps, hkv, hd).transpose(0, 2, 1, 3)
         r = base + jnp.arange(ppc * ps)      # logical slot index
@@ -371,22 +374,24 @@ _chunked_attention_diff.defvjp(_cad_fwd, _cad_bwd)
 
 def chunked_attention(q, k, v, *, causal=True, window: int = 0,
                       q_offset=0, kv_len=None, chunk: int = 1024,
-                      pages=None):
+                      pages=None, layer=None):
     """Online-softmax scan over KV chunks. q: (B,Hq,Sq,hd); k/v GQA.
 
     q_offset may be a traced scalar (decode). kv_len masks padded cache.
     The train path (static offset, no kv_len) uses the flash custom-VJP.
 
     pages: optional (B, n_logical_pages) int32 block table. When given,
-    k/v are page *pools* (n_pages, page_size, Hkv, hd) and every chunk
-    gathers its KV rows through the table (paged decode; causality and
-    windowing are expressed through kv_len by the caller).
+    k/v are stacked page *pools* (R, n_pages, page_size, Hkv, hd), read
+    at ``layer``, and every chunk gathers its KV rows through the table
+    (paged decode; causality and windowing are expressed through kv_len
+    by the caller).
     """
     b = q.shape[0]
     if pages is not None:
         limit = jnp.broadcast_to(jnp.asarray(kv_len), (b,))
         with jax.named_scope("rowwise_paged_attn"):
-            out, _ = _paged_fwd(q, k, v, pages, limit, chunk=chunk)
+            out, _ = _paged_fwd(q, k, v, pages, limit, layer=layer,
+                                chunk=chunk)
         return out
     skv = k.shape[2]
     limit = skv if kv_len is None else kv_len
@@ -520,20 +525,22 @@ def _decode_qkv(params, x, cfg: ModelConfig, lengths, norm):
 
 
 def write_pages(pool: PagedKVCache, k_new, v_new, pos, pages,
-                window: int = 0):
+                window: int = 0, *, layer):
     """Append the decode token's K/V (B,1,Hkv,hd) at logical position
     ``pos`` (B,) through the block table ``pages`` (B, n_logical).
     Windowed layers treat the first ``window // page_size`` table
     entries as a ring of pages (the paged analog of the dense ring
-    buffer's ``pos % window`` write)."""
-    ps = pool.k.shape[1]
+    buffer's ``pos % window`` write). The pool is the stacked (R, ...)
+    one and the scatter lands at ``(layer, pid, off)``: no layer's pool
+    is sliced out or written back."""
+    ps = pool.k.shape[2]
     r = pos if window == 0 else pos % window
     lp = jnp.clip(r // ps, 0, pages.shape[1] - 1)
     off = r % ps
     pid = jnp.take_along_axis(pages, lp[:, None], axis=1)[:, 0]   # (B,)
     return PagedKVCache(
-        k=pool.k.at[pid, off].set(k_new[:, 0].astype(pool.k.dtype)),
-        v=pool.v.at[pid, off].set(v_new[:, 0].astype(pool.v.dtype)))
+        k=pool.k.at[layer, pid, off].set(k_new[:, 0].astype(pool.k.dtype)),
+        v=pool.v.at[layer, pid, off].set(v_new[:, 0].astype(pool.v.dtype)))
 
 
 def _merge_partials(out_a, lse_a, out_b, lse_b):
@@ -557,7 +564,7 @@ def _merge_partials(out_a, lse_a, out_b, lse_b):
 
 
 def write_chunk_pages(pool: PagedKVCache, k_new, v_new, offset, chunk_len,
-                      pages, window: int = 0):
+                      pages, window: int = 0, *, layer):
     """Append a prefill chunk's K/V (B, Sc, Hkv, hd) at logical
     positions ``offset .. offset + chunk_len - 1`` through the block
     table ``pages`` (B, n_logical) — the multi-token generalization of
@@ -570,7 +577,8 @@ def write_chunk_pages(pool: PagedKVCache, k_new, v_new, offset, chunk_len,
     and keep only the chunk's last ``window`` positions — earlier rows
     would be clobbered by a later in-chunk position at the same ring
     slot, and no future query needs them — which also keeps the
-    scatter's target indices duplicate-free.
+    scatter's target indices duplicate-free. ``layer`` as in
+    :func:`write_pages`.
 
     Shared-page contract (PR 8): every page this scatter can touch —
     logical pages ``offset // ps .. (offset + chunk_len - 1) // ps`` —
@@ -580,7 +588,7 @@ def write_chunk_pages(pool: PagedKVCache, k_new, v_new, offset, chunk_len,
     :func:`copy_page` (``PagePool.cow``) before the first chunk that
     writes into it."""
     b, sc = k_new.shape[:2]
-    ps = pool.k.shape[1]
+    ps = pool.k.shape[2]
     i = jnp.arange(sc)
     offset = jnp.broadcast_to(jnp.asarray(offset), (b,))
     clen = jnp.broadcast_to(jnp.asarray(chunk_len), (b,))
@@ -592,13 +600,13 @@ def write_chunk_pages(pool: PagedKVCache, k_new, v_new, offset, chunk_len,
         r = pos % window
     lp = jnp.clip(r // ps, 0, pages.shape[1] - 1)              # (B, Sc)
     pid = jnp.where(valid, jnp.take_along_axis(pages, lp, axis=1),
-                    pool.k.shape[0])
+                    pool.k.shape[1])                   # OOB => dropped
     off = r % ps
     return PagedKVCache(
-        k=pool.k.at[pid, off].set(k_new.astype(pool.k.dtype),
-                                  mode="drop"),
-        v=pool.v.at[pid, off].set(v_new.astype(pool.v.dtype),
-                                  mode="drop"))
+        k=pool.k.at[layer, pid, off].set(k_new.astype(pool.k.dtype),
+                                         mode="drop"),
+        v=pool.v.at[layer, pid, off].set(v_new.astype(pool.v.dtype),
+                                         mode="drop"))
 
 
 def copy_page(pool: PagedKVCache, src, dst):
@@ -615,45 +623,37 @@ def copy_page(pool: PagedKVCache, src, dst):
                         v=pool.v.at[:, dst].set(pool.v[:, src]))
 
 
-def paged_chunk_apply(params, x, pool: PagedKVCache, *, cfg: ModelConfig,
+def paged_panel_apply(params, x, pool: PagedKVCache, *, cfg: ModelConfig,
                       offset, chunk_len, pages, window: int = 0,
-                      norm: Optional[ops.NormSpec] = None, residual=None):
-    """Chunked-prefill forward for one attention layer: a row panel of
-    ``Sc`` prompt tokens starting at absolute position ``offset``
-    ((B,) int32, traced), of which the first ``chunk_len`` are real
-    (right padding masked). x: (B, Sc, d). Returns (out, new_pool);
-    norm/residual as in :func:`apply`.
+                      norm: Optional[ops.NormSpec] = None, residual=None,
+                      layer):
+    """Panel forward for one attention layer — a chunked-prefill chunk
+    or a speculative-verify panel: ``Sc`` tokens per row starting at
+    absolute position ``offset`` ((B,) int32, traced), of which the
+    first ``chunk_len`` (scalar or (B,)) are real (right padding
+    masked). x: (B, Sc, d). norm/residual as in :func:`apply`;
+    ``layer`` indexes stacked pools (:func:`write_pages`). Returns
+    (out, (k, v)), the panel's K/V heads (B, Sc, Hkv, hd).
 
-    Attention is the exact softmax over prefix ∪ chunk, assembled from
+    Attention is the exact softmax over prefix ∪ panel, assembled from
     two partials sharing the row-wise ``_online_update`` math:
 
       * the already-written KV pages, via the multi-query
         :func:`_paged_fwd` prefix gather (per-query window masking,
         ring position recovery for sliding-window layers);
-      * the in-flight chunk itself, causally, via :func:`_chunked_fwd`
-        in chunk-relative coordinates (the window constraint is
+      * the panel itself, causally, via :func:`_chunked_fwd` in
+        panel-relative coordinates (the window constraint is
         translation-invariant);
 
-    merged by :func:`_merge_partials`. The chunk's own K/V then append
-    at the position offset (:func:`write_chunk_pages`) — strictly after
-    the prefix gather, so ring writes cannot clobber prefix keys the
-    chunk's queries still need.
+    merged by :func:`_merge_partials`. The pool is only read: the caller
+    appends the returned K/V afterwards (:func:`write_chunk_pages`, via
+    ``lm.insert_panel``) — strictly after the prefix gather, so ring
+    writes cannot clobber prefix keys the panel's queries still need.
+    Verify scores its logits first and writes only the accepted rows,
+    which keeps rejected drafts out of the pool entirely (a ring write
+    from a rejected row would clobber the very prefix keys the
+    re-decode of that position still needs).
     """
-    out, k, v = _chunk_attn_core(params, x, pool, cfg=cfg, offset=offset,
-                                 chunk_len=chunk_len, pages=pages,
-                                 window=window, norm=norm,
-                                 residual=residual)
-    pool = write_chunk_pages(pool, k, v, offset, chunk_len, pages,
-                             window)
-    return out, pool
-
-
-def _chunk_attn_core(params, x, pool: PagedKVCache, *, cfg: ModelConfig,
-                     offset, chunk_len, pages, window: int,
-                     norm: Optional[ops.NormSpec], residual):
-    """Shared math of :func:`paged_chunk_apply` /
-    :func:`paged_verify_apply`: exact softmax over prefix ∪ chunk with
-    no pool mutation. Returns (projected out, chunk k, chunk v)."""
     b, sc, d = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     positions = offset[:, None] + jnp.arange(sc, dtype=jnp.int32)[None]
@@ -669,46 +669,26 @@ def _chunk_attn_core(params, x, pool: PagedKVCache, *, cfg: ModelConfig,
     with jax.named_scope("rowwise_chunk_attn"):
         out_c, lse_c = _chunked_fwd(qh, kh, vh, clen, causal=True,
                                     window=window, q_offset=0, chunk=1024)
-        ps = pool.k.shape[1]
+        ps = pool.k.shape[2]
         tbl = pages[:, :max(window // ps, 1)] if window else pages
         out_p, lse_p = _paged_fwd(qh, pool.k, pool.v, tbl,
                                   jnp.broadcast_to(jnp.asarray(offset),
                                                    (b,)),
                                   chunk=1024, q_offset=offset,
-                                  window=window)
+                                  window=window, layer=layer)
         out = _merge_partials(out_c, lse_c, out_p, lse_p)
     out = out.transpose(0, 2, 1, 3).reshape(b, sc, hq * hd)
-    return _out_proj(out, params["wo"], residual), k, v
-
-
-def paged_verify_apply(params, x, pool: PagedKVCache, *,
-                       cfg: ModelConfig, offset, chunk_len, pages,
-                       window: int = 0,
-                       norm: Optional[ops.NormSpec] = None,
-                       residual=None):
-    """Speculative-verify forward for one attention layer: bit-identical
-    attention math to :func:`paged_chunk_apply` over the draft panel
-    (the panel is causal over itself plus the slot's written prefix),
-    but the panel's K/V are NOT written to the pool — they are returned
-    so the engine can score the logits first and then write only the
-    accepted prefix rows (:func:`write_chunk_pages` with per-row
-    accepted lengths). Deferring the write keeps rejected drafts out of
-    the pool entirely, which matters for sliding-window layers: a ring
-    write from a rejected row would clobber the very prefix keys the
-    re-decode of that position still needs. Returns (out, (k, v))."""
-    out, k, v = _chunk_attn_core(params, x, pool, cfg=cfg, offset=offset,
-                                 chunk_len=chunk_len, pages=pages,
-                                 window=window, norm=norm,
-                                 residual=residual)
-    return out, (k, v)
+    return _out_proj(out, params["wo"], residual), (k, v)
 
 
 def paged_decode_apply(params, x, pool: PagedKVCache, *, cfg: ModelConfig,
                        lengths, pages, window: int = 0,
-                       norm: Optional[ops.NormSpec] = None, residual=None):
+                       norm: Optional[ops.NormSpec] = None, residual=None,
+                       layer):
     """One-token decode against a paged KV pool. x: (B, 1, d); lengths:
     (B,) tokens already written; pages: (B, max_pages) block table.
-    Returns (out, new_pool). norm/residual as in :func:`apply`.
+    Returns (out, new_pool). norm/residual as in :func:`apply`;
+    ``layer`` indexes stacked pools (:func:`write_pages`).
 
     The attention core is the same online-softmax row-wise primitive as
     the dense path, but each chunk gathers only the slot's live pages —
@@ -718,8 +698,8 @@ def paged_decode_apply(params, x, pool: PagedKVCache, *, cfg: ModelConfig,
     b = x.shape[0]
     hq, hd = cfg.n_heads, cfg.head_dim
     q, k, v = _decode_qkv(params, x, cfg, lengths, norm)
-    pool = write_pages(pool, k, v, lengths, pages, window)
-    ps = pool.k.shape[1]
+    pool = write_pages(pool, k, v, lengths, pages, window, layer=layer)
+    ps = pool.k.shape[2]
     if window:
         tbl = pages[:, :max(window // ps, 1)]
         kv_len = jnp.minimum(lengths + 1, window)
@@ -728,7 +708,7 @@ def paged_decode_apply(params, x, pool: PagedKVCache, *, cfg: ModelConfig,
         kv_len = lengths + 1
     qh = q.transpose(0, 2, 1, 3)
     out = chunked_attention(qh, pool.k, pool.v, causal=False, window=0,
-                            kv_len=kv_len, pages=tbl)
+                            kv_len=kv_len, pages=tbl, layer=layer)
     out = out.reshape(b, 1, hq * hd)
     return _out_proj(out, params["wo"], residual), pool
 

@@ -76,27 +76,27 @@ class BlockIO(NamedTuple):
 
 def apply_block(blk: BlockDef, params, x, *, cfg: ModelConfig, mode: str,
                 positions=None, lengths=None, cache=None, enc_out=None,
-                pages=None, chunk_len=None,
+                pages=None, chunk_len=None, layer=None,
                 window_override: Optional[int] = None) -> tuple:
-    """mode: 'train' | 'prefill' | 'decode' | 'chunk' | 'verify'.
+    """mode: 'train' | 'prefill' | 'decode' | 'panel'.
     Returns (x, BlockIO).
 
     pages: (B, max_pages) int32 block table for paged decode — required
     when the decode cache's KV leaf is a :class:`PagedKVCache` pool.
-    'chunk' is the serving engine's chunked-prefill mode: x is a row
-    panel of prompt tokens at position offset ``lengths`` (the tokens
-    already in the paged cache, exactly the decode-mode semantics) of
-    which the first ``chunk_len`` are real; attention layers attend
-    prefix pages + the in-flight chunk and append their KV. Only
-    causal-attention archs may chunk (``paging.supports_bucketing`` —
-    recurrent mixers would fold the split into their state).
-    'verify' is the speculative-decode scoring mode: same panel
-    semantics as 'chunk' (now batched, per-row offsets/lengths) but the
-    pool is read-only — each layer returns its panel (k, v) as
-    ``prefill_state`` and the engine writes only accepted rows after
-    acceptance (:func:`lm.insert_verify`).
+    layer: the (traced) layer index into that pool's stacked leaves
+    (the layer scan carries paged pools whole; :func:`lm._run_stage`).
+    'panel' is the serving engine's chunked-prefill and speculative
+    verify mode: x is a row panel of tokens at position offset
+    ``lengths`` (the tokens already in the paged cache, exactly the
+    decode-mode semantics; per row) of which the first ``chunk_len``
+    are real; attention layers attend prefix pages + the panel, leave
+    the pool untouched and return the panel's (k, v) as
+    ``prefill_state``, which the caller writes afterwards
+    (:func:`lm.insert_panel`). Only causal-attention archs may take
+    panels (``paging.supports_bucketing`` — recurrent mixers would fold
+    the split into their state).
     """
-    if mode in ("chunk", "verify"):
+    if mode == "panel":
         assert blk.mixer == "attn" and not blk.cross_attn, (
             "chunked prefill requires every position's state to be "
             f"causal-attention KV; {blk.mixer}/cross_attn blocks must "
@@ -120,24 +120,18 @@ def apply_block(blk: BlockDef, params, x, *, cfg: ModelConfig, mode: str,
                 out, kv_new = attention.paged_decode_apply(
                     params["attn"], h, cache["kv"], cfg=cfg,
                     lengths=lengths, pages=pages, window=window,
-                    norm=nspec, residual=res)
+                    norm=nspec, residual=res, layer=layer)
             else:
                 out, kv_new = attention.decode_apply(
                     params["attn"], h, cache["kv"], cfg=cfg,
                     lengths=lengths, window=window, norm=nspec,
                     residual=res)
             new_cache["kv"] = kv_new
-        elif mode == "chunk":
-            out, kv_new = attention.paged_chunk_apply(
+        elif mode == "panel":
+            out, (k, v) = attention.paged_panel_apply(
                 params["attn"], h, cache["kv"], cfg=cfg, offset=lengths,
                 chunk_len=chunk_len, pages=pages, window=window,
-                norm=nspec, residual=res)
-            new_cache["kv"] = kv_new
-        elif mode == "verify":
-            out, (k, v) = attention.paged_verify_apply(
-                params["attn"], h, cache["kv"], cfg=cfg, offset=lengths,
-                chunk_len=chunk_len, pages=pages, window=window,
-                norm=nspec, residual=res)
+                norm=nspec, residual=res, layer=layer)
             prefill_state["kv"] = (k, v)
         else:
             out, (k, v) = attention.apply(params["attn"], h, cfg=cfg,

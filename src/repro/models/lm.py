@@ -207,41 +207,68 @@ def unfuse_params(cfg: ModelConfig, params):
 # ----------------------------------------------------------------------
 
 
+def _is_pool(leaf) -> bool:
+    return isinstance(leaf, attention.PagedKVCache)
+
+
 def _run_stage(stage: Stage, sp, x, *, cfg: ModelConfig, mode: str,
                positions=None, lengths=None, cache=None, enc_out=None,
                pages=None, chunk_len=None, causal=True, remat=False):
     """Scan a stage. Returns (x, aux, new_cache_or_prefill_states).
     ``pages`` (the serving block table) is scan-invariant: every layer
-    indexes its own pool through the same per-slot table."""
+    indexes its own pool through the same per-slot table.
+
+    Paged KV pools ride in the scan *carry*, stacked (R, P, ps, Hkv,
+    hd), and each layer gathers and scatters its own rows through the
+    scanned layer index; with the cache donated the scatter updates the
+    while loop's buffer in place. Scanned as ``xs``/``ys`` instead, each
+    step would copy every layer's pool out and write it back whole.
+    'panel' mode only reads the pools; its caller writes the panel's
+    K/V after the scan (:func:`insert_panel`). The other cache leaves
+    (dense KV, recurrent state, cross-KV) are per-layer inputs and
+    outputs of the scan."""
     stacked, shared = sp["stacked"], sp["shared"]
+    cache = cache or {}
+    pools = {key: {n: c for n, c in entry.items() if _is_pool(c)}
+             for key, entry in cache.items()}
+    pools = {key: p for key, p in pools.items() if p}
+    per_layer = {key: {n: c for n, c in entry.items() if not _is_pool(c)}
+                 for key, entry in cache.items()}
 
     def body(carry, xs):
-        x, aux = carry
-        sliced, cache_slice = xs
+        x, aux, pools = carry
+        layer, sliced, cache_slice = xs
+        pools = dict(pools)
         out_states = {}
         for i, blk in enumerate(stage.body):
             key = str(i)
             bp = sliced[key] if key in sliced else shared[key]
-            csl = cache_slice.get(key) if cache_slice else None
+            csl = {**cache_slice.get(key, {}), **pools.get(key, {})} or None
             x, io = blocks.apply_block(
                 blk, bp, x, cfg=cfg, mode=mode, positions=positions,
                 lengths=lengths, cache=csl, enc_out=enc_out, pages=pages,
-                chunk_len=chunk_len,
+                chunk_len=chunk_len, layer=layer,
                 window_override=None if causal else 0)
             aux = aux + io.aux
-            if mode in ("decode", "chunk") and io.new_cache is not None:
-                out_states[key] = io.new_cache
-            elif (mode in ("prefill", "verify")
+            if mode == "decode" and io.new_cache is not None:
+                new = dict(io.new_cache)
+                if key in pools:
+                    pools[key] = {n: new.pop(n) for n in pools[key]}
+                out_states[key] = new
+            elif (mode in ("prefill", "panel")
                     and io.prefill_state is not None):
                 out_states[key] = io.prefill_state
-        return (x, aux), out_states
+        return (x, aux, pools), out_states
 
     if remat and mode == "train":
         body = jax.checkpoint(body, prevent_cse=False)
 
-    xs = (stacked, cache) if cache is not None else (stacked, {})
-    (x, aux), states = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                    xs, length=stage.repeat)
+    (x, aux, pools), states = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), pools),
+        (jnp.arange(stage.repeat), stacked, per_layer), length=stage.repeat)
+    if mode == "decode":
+        for key, p in pools.items():
+            states[key] = {**states.get(key, {}), **p}
     return x, aux, states
 
 
@@ -633,6 +660,30 @@ def insert_prefill(cfg: ModelConfig, cache, states, *, slot, pages, plen,
     return out
 
 
+def _panel_forward(params, cache, tokens, cfg: ModelConfig, *, offset,
+                   chunk_len, pages):
+    """Shared forward of :func:`prefill_chunk` and :func:`verify_states`:
+    a (B, Sc) row panel at absolute positions ``offset + i`` attends
+    each slot's written KV pages plus the panel itself
+    (``attention.paged_panel_apply``), with the pools read-only.
+    ``offset``/``chunk_len``: scalar or per-row (B,) int32 (tokens
+    already in the cache / real panel rows; right padding is masked).
+    Returns (hidden states (B, Sc, d), per-layer panel KV states)."""
+    b, s = tokens.shape
+    offset = jnp.broadcast_to(jnp.asarray(offset, jnp.int32), (b,))
+    x = embed(params, tokens, cfg, None)
+    x = logical_constraint(x, "batch", "seq", "act_embed")
+    if cfg.rope == "none" and not cfg.encdec:
+        pe = rope.sinusoidal_embedding(1 << 16, cfg.d_model)
+        pos = offset[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
+        x = x + pe[pos].astype(x.dtype)
+    x, _, states = _run_stages(params["stages"], cfg.stages(), x,
+                               cfg=cfg, mode="panel", positions=None,
+                               lengths=offset, cache=cache, pages=pages,
+                               chunk_len=chunk_len, remat=False)
+    return x, states
+
+
 def prefill_chunk(params, cache, tokens, cfg: ModelConfig, *, offset,
                   chunk_len, pages):
     """Chunked-prefill step: one ``prefill_states``-style forward over a
@@ -643,33 +694,27 @@ def prefill_chunk(params, cache, tokens, cfg: ModelConfig, *, offset,
     paged cache), right-padded to a static chunk shape with the true
     length in ``chunk_len`` (traced, <= Sc_pad). Every attention layer
     attends the slot's already-written KV pages plus the in-flight
-    chunk (``attention.paged_chunk_apply``) and appends the chunk's KV
-    at the position offset, so successive calls rebuild exactly the KV
-    state one-shot prefill + ``insert_prefill`` would have written.
+    chunk (:func:`_panel_forward`); then every layer's chunk KV is
+    appended at the position offset (:func:`insert_panel`), so
+    successive calls rebuild exactly the KV state one-shot prefill +
+    ``insert_prefill`` would have written. Writing after the whole scan
+    puts each layer's write after its prefix gather by data dependence,
+    which lets XLA update the pools in place.
     Returns (next-token logits (1, V) at chunk position chunk_len - 1,
     new_cache). Only causal-attention archs may chunk (the engine gates
     on ``paging.supports_bucketing``); the final chunk's logits are the
     prompt's first-token logits.
     """
-    b, s = tokens.shape
-    offset = jnp.asarray(offset, jnp.int32)
-    x = embed(params, tokens, cfg, None)
-    x = logical_constraint(x, "batch", "seq", "act_embed")
-    if cfg.rope == "none" and not cfg.encdec:
-        pe = rope.sinusoidal_embedding(1 << 16, cfg.d_model)
-        x = x + jax.lax.dynamic_slice_in_dim(pe, offset, s,
-                                             axis=0)[None].astype(x.dtype)
-    lengths = jnp.broadcast_to(offset, (b,))
-    x, _, new_cache = _run_stages(params["stages"], cfg.stages(), x,
-                                  cfg=cfg, mode="chunk", positions=None,
-                                  lengths=lengths, cache=cache,
-                                  pages=pages, chunk_len=chunk_len,
-                                  remat=False)
+    b = tokens.shape[0]
+    x, states = _panel_forward(params, cache, tokens, cfg, offset=offset,
+                               chunk_len=chunk_len, pages=pages)
     idx = (jnp.asarray(chunk_len, jnp.int32) - 1)[None, None, None]
     xl = jnp.take_along_axis(
         x, jnp.broadcast_to(idx, (b, 1, x.shape[-1])), axis=1)
     logits = unembed(params, xl, cfg)
-    return logits[:, 0], new_cache
+    cache = insert_panel(cfg, cache, states, pages=pages, offset=offset,
+                         n_keep=chunk_len)
+    return logits[:, 0], cache
 
 
 def verify_states(params, cache, tokens, cfg: ModelConfig, *, offset,
@@ -683,37 +728,27 @@ def verify_states(params, cache, tokens, cfg: ModelConfig, *, offset,
     Returns (full panel logits (B, Sc, V), per-layer panel KV states) —
     logits, not a gathered position, because acceptance needs every
     panel position's distribution; the caller then writes only accepted
-    rows via :func:`insert_verify`. The split mirrors the
+    rows via :func:`insert_panel`. The split mirrors the
     ``prefill_states`` / ``insert_prefill`` pair: forward first, commit
     separately. Only causal-attention archs verify (the engine gates on
     ``paging.supports_bucketing``)."""
-    b, s = tokens.shape
-    offset = jnp.broadcast_to(jnp.asarray(offset, jnp.int32), (b,))
-    x = embed(params, tokens, cfg, None)
-    x = logical_constraint(x, "batch", "seq", "act_embed")
-    if cfg.rope == "none" and not cfg.encdec:
-        pe = rope.sinusoidal_embedding(1 << 16, cfg.d_model)
-        pos = offset[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
-        x = x + pe[pos].astype(x.dtype)
-    x, _, states = _run_stages(params["stages"], cfg.stages(), x,
-                               cfg=cfg, mode="verify", positions=None,
-                               lengths=offset, cache=cache, pages=pages,
-                               chunk_len=chunk_len, remat=False)
+    x, states = _panel_forward(params, cache, tokens, cfg, offset=offset,
+                               chunk_len=chunk_len, pages=pages)
     return unembed(params, x, cfg), states
 
 
-def insert_verify(cfg: ModelConfig, cache, states, *, pages, offset,
-                  n_keep):
-    """Write the accepted prefix of a verify panel into the paged cache:
-    every attention layer scatters its panel rows ``< n_keep[b]`` (per
-    row: the re-scored committed token plus the accepted drafts;
-    ``n_keep == 0`` writes nothing — inactive or fully-rolled-back
-    slots). The layer walk mirrors :func:`insert_prefill`; verify
-    states only ever hold attention KV (verify requires a
-    bucketing-capable, attention-only arch). The per-layer scatter is
-    :func:`attention.write_chunk_pages` vmapped over the scan-stacked
-    layer axis, so accepted writes reuse the chunked-prefill scatter
-    (including windowed ring routing) exactly."""
+def insert_panel(cfg: ModelConfig, cache, states, *, pages, offset,
+                 n_keep):
+    """Write panel KV states into the paged cache: every attention layer
+    scatters its panel rows ``< n_keep`` (scalar or per-row: a chunk's
+    real rows, or the re-scored committed token plus the accepted
+    drafts; ``n_keep == 0`` writes nothing — inactive or
+    fully-rolled-back slots). The layer walk mirrors
+    :func:`insert_prefill`; panel states only ever hold attention KV
+    (chunking and verify require a bucketing-capable, attention-only
+    arch). A loop over the stacked layer axis scatters each layer's rows
+    with :func:`attention.write_chunk_pages` (windowed ring routing
+    included) into the stacked pool, in place."""
     out = []
     for si, stage in enumerate(cfg.stages()):
         sc = {}
@@ -727,14 +762,12 @@ def insert_verify(cfg: ModelConfig, cache, states, *, pages, offset,
             if "kv" in st:
                 k, v = st["kv"]
 
-                def wr(pk, pv, kk, vv, window=blk.window):
-                    pool = attention.write_chunk_pages(
-                        attention.PagedKVCache(k=pk, v=pv), kk, vv,
-                        offset, n_keep, pages, window)
-                    return pool.k, pool.v
+                def wr(layer, pool, k=k, v=v, window=blk.window):
+                    return attention.write_chunk_pages(
+                        pool, k[layer], v[layer], offset, n_keep, pages,
+                        window, layer=layer)
 
-                nk, nv = jax.vmap(wr)(cur["kv"].k, cur["kv"].v, k, v)
-                c["kv"] = attention.PagedKVCache(k=nk, v=nv)
+                c["kv"] = jax.lax.fori_loop(0, stage.repeat, wr, cur["kv"])
             sc[key] = c
         out.append(sc)
     return out
@@ -748,10 +781,8 @@ def cow_copy(cache, src, dst):
     step — non-COW chunks pass ``(0, 0)`` and compile the same program.
     Non-attention state (recurrent, cross-KV) is untouched."""
     return jax.tree.map(
-        lambda c: (attention.copy_page(c, src, dst)
-                   if isinstance(c, attention.PagedKVCache) else c),
-        cache,
-        is_leaf=lambda c: isinstance(c, attention.PagedKVCache))
+        lambda c: attention.copy_page(c, src, dst) if _is_pool(c) else c,
+        cache, is_leaf=_is_pool)
 
 
 def decode_step(params, cache, tokens, lengths, cfg: ModelConfig,

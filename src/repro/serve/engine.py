@@ -498,16 +498,19 @@ class Engine:
                             toks)
             # write ONLY the committed token plus the accepted prefix
             n_keep = jnp.where(active, 1 + n_acc, 0)
-            cache = lm.insert_verify(rcfg, cache, states, pages=tables,
-                                     offset=lengths, n_keep=n_keep)
+            cache = lm.insert_panel(rcfg, cache, states, pages=tables,
+                                    offset=lengths, n_keep=n_keep)
             n_acc = jnp.where(active, n_acc, 0).astype(jnp.int32)
             new_lengths = jnp.where(active, lengths + 1 + n_acc, 0)
             return nxt, n_acc, bad, new_lengths, cache
 
-        # donate the cache: the pool update aliases in place instead of
-        # copying the whole (R, n_pages + n_slots, ps, Hkv, hd) pools
-        # every step. Placement owns the jit: under TP the entry points
-        # run in shard_map over the mesh, host operands replicated.
+        # donate the cache: the stacked (R, n_pages + n_slots, ps, Hkv,
+        # hd) pools enter each program's buffers, and the layer scan
+        # carries them and scatters every layer's rows at (layer, page,
+        # offset) (lm._run_stage), so a step updates the pools in place
+        # and never copies one. Placement owns the jit: under TP the
+        # entry points run in shard_map over the mesh, host operands
+        # replicated.
         self._step = self.placement.jit(
             decode_step, kinds=(PARAMS, CACHE) + (REP,) * 7,
             out_kinds=(REP, REP, REP, CACHE), donate=(1,))

@@ -59,8 +59,9 @@ def test_chunk_schedule_shapes_stay_on_ladder():
 
 
 def _empty_pool(n_pages, ps, hkv=2, hd=4):
-    return PagedKVCache(k=jnp.zeros((n_pages, ps, hkv, hd)),
-                        v=jnp.zeros((n_pages, ps, hkv, hd)))
+    """A one-layer stack of pools, written as layer 0."""
+    return PagedKVCache(k=jnp.zeros((1, n_pages, ps, hkv, hd)),
+                        v=jnp.zeros((1, n_pages, ps, hkv, hd)))
 
 
 def test_write_chunk_pages_positions_and_padding():
@@ -73,14 +74,14 @@ def test_write_chunk_pages_positions_and_padding():
     # offset 5, chunk_len 3: positions 5,6,7 -> logical page 1 (phys 0)
     # offsets 1,2,3; the padded row 3 (would-be position 8) is dropped
     pool = write_chunk_pages(pool, k_new, 2 * k_new, jnp.int32(5),
-                             jnp.int32(3), tables)
-    assert bool(jnp.all(pool.k[0, 1] == 1.0))
-    assert bool(jnp.all(pool.k[0, 2] == 2.0))
-    assert bool(jnp.all(pool.k[0, 3] == 3.0))
-    assert bool(jnp.all(pool.v[0, 1] == 2.0))
+                             jnp.int32(3), tables, layer=0)
+    assert bool(jnp.all(pool.k[0, 0, 1] == 1.0))
+    assert bool(jnp.all(pool.k[0, 0, 2] == 2.0))
+    assert bool(jnp.all(pool.k[0, 0, 3] == 3.0))
+    assert bool(jnp.all(pool.v[0, 0, 1] == 2.0))
     # nothing else written anywhere (padding dropped, page 2 untouched)
     assert float(jnp.abs(pool.k).sum()) == (1 + 2 + 3) * hkv * hd
-    assert float(jnp.abs(pool.k[0, 0]).sum()) == 0.0
+    assert float(jnp.abs(pool.k[0, 0, 0]).sum()) == 0.0
 
 
 def test_write_chunk_pages_ring_wraps_window():
@@ -93,11 +94,11 @@ def test_write_chunk_pages_ring_wraps_window():
     # window=8: positions 6..9 -> ring idx 6,7,0,1 -> (phys 2, off 2/3)
     # and wrap to (phys 1, off 0/1)
     pool = write_chunk_pages(pool, k_new, k_new, jnp.int32(6),
-                             jnp.int32(4), tables, window=8)
-    assert bool(jnp.all(pool.k[2, 2] == 1.0))
-    assert bool(jnp.all(pool.k[2, 3] == 2.0))
-    assert bool(jnp.all(pool.k[1, 0] == 3.0))
-    assert bool(jnp.all(pool.k[1, 1] == 4.0))
+                             jnp.int32(4), tables, window=8, layer=0)
+    assert bool(jnp.all(pool.k[0, 2, 2] == 1.0))
+    assert bool(jnp.all(pool.k[0, 2, 3] == 2.0))
+    assert bool(jnp.all(pool.k[0, 1, 0] == 3.0))
+    assert bool(jnp.all(pool.k[0, 1, 1] == 4.0))
 
 
 def test_write_chunk_pages_keeps_only_last_window_of_chunk():
@@ -113,11 +114,11 @@ def test_write_chunk_pages_keeps_only_last_window_of_chunk():
              * jnp.ones((1, sc, hkv, hd)))
     # window=4, positions 0..5: keep 2..5 at ring idx 2,3,0,1
     pool = write_chunk_pages(pool, k_new, k_new, jnp.int32(0),
-                             jnp.int32(6), tables, window=4)
-    assert bool(jnp.all(pool.k[0, 0] == 3.0))     # pos 2 -> phys 0 off 0
-    assert bool(jnp.all(pool.k[0, 1] == 4.0))
-    assert bool(jnp.all(pool.k[1, 0] == 5.0))     # pos 4 wraps
-    assert bool(jnp.all(pool.k[1, 1] == 6.0))
+                             jnp.int32(6), tables, window=4, layer=0)
+    assert bool(jnp.all(pool.k[0, 0, 0] == 3.0))  # pos 2 -> phys 0 off 0
+    assert bool(jnp.all(pool.k[0, 0, 1] == 4.0))
+    assert bool(jnp.all(pool.k[0, 1, 0] == 5.0))  # pos 4 wraps
+    assert bool(jnp.all(pool.k[0, 1, 1] == 6.0))
 
 
 # ----------------------------------------------------------------------
@@ -187,8 +188,9 @@ def test_chunk_attention_matches_dense_causal(window, off, rng):
     else:
         pool_k, pool_v, tables = _linear_pool(k, v, max(off, 1), ps, rng)
     offs = jnp.full((b,), off, jnp.int32)
-    out_p, lse_p = _paged_fwd(q, pool_k, pool_v, tables, offs,
-                              chunk=1024, q_offset=offs, window=window)
+    out_p, lse_p = _paged_fwd(q, pool_k[None], pool_v[None], tables, offs,
+                              layer=0, chunk=1024, q_offset=offs,
+                              window=window)
     out = _merge_partials(out_c, lse_c, out_p, lse_p)
     assert float(jnp.max(jnp.abs(out - ref))) < 1e-5
 

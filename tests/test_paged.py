@@ -9,11 +9,14 @@ slot reuse, while compiling at most ``n_buckets + 1`` programs
 (``n_buckets + n_chunk_shapes + 1`` once chunked prefill is on —
 chunked-path parity itself lives in ``test_chunked_prefill.py``).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import manual_greedy
+from conftest import (layerwise_paged_step, manual_greedy,
+                      random_paged_cache)
 
 from repro.analysis import compile_bound
 from repro.configs import REDUCED
@@ -100,7 +103,8 @@ def test_engine_rejects_bad_bucket_overrides():
 
 
 def _build_pool(k, v, page_size, rng):
-    """Scatter dense (B,S,Hkv,hd) states into a shuffled page pool."""
+    """Scatter dense (B,S,Hkv,hd) states into a shuffled page pool, the
+    one layer of a (1, n_pages + 1, page_size, Hkv, hd) stack."""
     b, s, hkv, hd = k.shape
     npp = s // page_size
     n_pages = b * npp
@@ -113,7 +117,8 @@ def _build_pool(k, v, page_size, rng):
             sl = slice(p * page_size, (p + 1) * page_size)
             pool_k[tables[bi, p]] = np.asarray(k[bi, sl])
             pool_v[tables[bi, p]] = np.asarray(v[bi, sl])
-    return jnp.asarray(pool_k), jnp.asarray(pool_v), jnp.asarray(tables)
+    return (jnp.asarray(pool_k)[None], jnp.asarray(pool_v)[None],
+            jnp.asarray(tables))
 
 
 @pytest.mark.parametrize("chunk", [1024, 8])
@@ -132,7 +137,7 @@ def test_paged_attention_matches_chunked(chunk):
                                          np.random.default_rng(0))
     out = attention.chunked_attention(q, pool_k, pool_v, causal=False,
                                       window=0, kv_len=lengths,
-                                      pages=tables, chunk=chunk)
+                                      pages=tables, layer=0, chunk=chunk)
     if chunk >= s:       # one online-softmax step each: the same sums,
         # but XLA:CPU may fuse the gathered and the dense dot products
         # with different FMA contraction, so allow a few float32 ulps
@@ -144,17 +149,19 @@ def test_paged_attention_matches_chunked(chunk):
 
 def test_write_pages_appends_to_tail_page():
     b, hkv, hd, ps = 2, 2, 4, 4
-    pool = attention.PagedKVCache(k=jnp.zeros((5, ps, hkv, hd)),
-                                  v=jnp.zeros((5, ps, hkv, hd)))
+    # two stacked layers: the write lands in layer 1 only
+    pool = attention.PagedKVCache(k=jnp.zeros((2, 5, ps, hkv, hd)),
+                                  v=jnp.zeros((2, 5, ps, hkv, hd)))
     tables = jnp.asarray([[2, 0], [3, 1]], jnp.int32)
     k_new = jnp.ones((b, 1, hkv, hd))
     v_new = 2 * jnp.ones((b, 1, hkv, hd))
     # slot 0 at position 5 => logical page 1 (physical 0), offset 1;
     # slot 1 at position 2 => logical page 0 (physical 3), offset 2
     pool = attention.write_pages(pool, k_new, v_new,
-                                 jnp.asarray([5, 2]), tables)
-    assert bool(jnp.all(pool.k[0, 1] == 1.0))
-    assert bool(jnp.all(pool.v[3, 2] == 2.0))
+                                 jnp.asarray([5, 2]), tables,
+                                 layer=jnp.int32(1))
+    assert bool(jnp.all(pool.k[1, 0, 1] == 1.0))
+    assert bool(jnp.all(pool.v[1, 3, 2] == 2.0))
     assert float(jnp.abs(pool.k).sum()) == hkv * hd * b   # nothing else
 
 
@@ -172,28 +179,139 @@ def test_idle_slot_writes_do_not_alias_one_page():
     # device-level: two idle slots' lockstep writes land on their own
     # scratch pages and nothing aliases
     hkv, hd, ps = 2, 4, 4
-    pool = attention.PagedKVCache(k=jnp.zeros((4, ps, hkv, hd)),
-                                  v=jnp.zeros((4, ps, hkv, hd)))
+    pool = attention.PagedKVCache(k=jnp.zeros((1, 4, ps, hkv, hd)),
+                                  v=jnp.zeros((1, 4, ps, hkv, hd)))
     tables = jnp.asarray([[2, 2], [3, 3]], jnp.int32)   # scratch = 2, 3
     k_new = jnp.stack([jnp.full((1, hkv, hd), 1.0),
                        jnp.full((1, hkv, hd), 5.0)])
     pool = attention.write_pages(pool, k_new, k_new,
-                                 jnp.asarray([0, 0]), tables)
-    assert bool(jnp.all(pool.k[2, 0] == 1.0))
-    assert bool(jnp.all(pool.k[3, 0] == 5.0))
-    assert float(jnp.abs(pool.k[:2]).sum()) == 0.0      # real pages clean
+                                 jnp.asarray([0, 0]), tables, layer=0)
+    assert bool(jnp.all(pool.k[0, 2, 0] == 1.0))
+    assert bool(jnp.all(pool.k[0, 3, 0] == 5.0))
+    assert float(jnp.abs(pool.k[0, :2]).sum()) == 0.0   # real pages clean
 
 
 def test_write_pages_ring_wraps_window():
     hkv, hd, ps = 1, 2, 4
-    pool = attention.PagedKVCache(k=jnp.zeros((4, ps, hkv, hd)),
-                                  v=jnp.zeros((4, ps, hkv, hd)))
+    pool = attention.PagedKVCache(k=jnp.zeros((1, 4, ps, hkv, hd)),
+                                  v=jnp.zeros((1, 4, ps, hkv, hd)))
     tables = jnp.asarray([[1, 2, 0]], jnp.int32)   # ring = first 2 pages
     # window=8: position 9 wraps to ring index 1 => page 0 (phys 1) off 1
     pool = attention.write_pages(pool, jnp.ones((1, 1, hkv, hd)),
                                  jnp.ones((1, 1, hkv, hd)),
-                                 jnp.asarray([9]), tables, window=8)
-    assert bool(jnp.all(pool.k[1, 1] == 1.0))
+                                 jnp.asarray([9]), tables, window=8,
+                                 layer=0)
+    assert bool(jnp.all(pool.k[0, 1, 1] == 1.0))
+
+
+# ----------------------------------------------------------------------
+# The layer scan carries the stacked pools (fast)
+# ----------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("arch", ["deepseek-7b", "gemma3-27b"])
+@pytest.mark.parametrize("mode", ["decode", "chunk"])
+def test_carried_pools_match_layerwise_oracle(arch, mode):
+    """The scan that carries the stacked pools and addresses each
+    layer by index gives logits and pools identical to a Python loop
+    that slices each layer's pool, runs the attention functions on it
+    and restacks. gemma3's 16-token windows are passed by both the
+    decode lengths and the chunk offset, so ring writes and reads
+    wrap."""
+    cfg = REDUCED[arch]()
+    key = jax.random.PRNGKey(0)
+    params, _ = lm.init_lm(key, cfg, dtype=jnp.float32)
+    cache, tables = random_paged_cache(cfg, key)
+    if mode == "decode":
+        args = (params, cache, jnp.asarray([[3], [7]], jnp.int32),
+                jnp.asarray([5, 37], jnp.int32), tables)
+        new = jax.jit(lambda p, c, t, n, tb: lm.decode_step(
+            p, c, t, n, cfg, pages=tb))
+        old = jax.jit(lambda p, c, t, n, tb: layerwise_paged_step(
+            p, c, t, cfg, pages=tb, lengths=n))
+    else:
+        args = (params, cache, jax.random.randint(key, (1, 16), 0,
+                                                  cfg.vocab),
+                jnp.int32(21), jnp.int32(12), tables[1:])
+        new = jax.jit(lambda p, c, t, o, n, tb: lm.prefill_chunk(
+            p, c, t, cfg, offset=o, chunk_len=n, pages=tb))
+        old = jax.jit(lambda p, c, t, o, n, tb: layerwise_paged_step(
+            p, c, t, cfg, pages=tb, offset=o, chunk_len=n))
+    logits, pools = new(*args)
+    want_logits, want_pools = old(*args)
+    # bitwise equal where XLA:CPU compiles the scan and the unrolled
+    # loop alike (decode, deepseek's chunk); gemma3's chunk fuses the
+    # multiply-adds of its windowed layers differently and reads up to
+    # 2.2e-6 apart on unit-scale pools. A wrong layer, page or write
+    # order moves values by O(1).
+    tol = dict(rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(logits),
+                               np.asarray(want_logits), **tol)
+    for got, want in zip(jax.tree.leaves(pools),
+                         jax.tree.leaves(want_pools)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   **tol)
+    # the step really wrote: the new K/V differ from the random prefix
+    assert any(bool(jnp.any(a != b)) for a, b in
+               zip(jax.tree.leaves(pools), jax.tree.leaves(cache)))
+
+
+# every instruction ``%name = f32[2,258,...]{...} op(%a, %b, ...)`` of a
+# compiled module, fused computations included
+_HLO_OP = re.compile(r"%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(([^)]*)\)")
+
+
+def _pool_traffic_ops(hlo: str, pools):
+    """The ops of a compiled program that move a whole pool: a ``copy``
+    of a stacked pool or of one layer's pool, a ``dynamic-slice`` that
+    takes one layer's pool out of the stack, and a
+    ``dynamic-update-slice`` whose update is at least one layer's pool
+    (a one-page copy-on-write update is not)."""
+    stacked = {tuple(p.k.shape) for p in pools}
+    layer = {s[1:] for s in stacked}
+    layer_size = min(int(np.prod(s)) for s in layer)
+    ops = _HLO_OP.findall(hlo)
+    shapes = {name: tuple(int(d) for d in dims.split(",") if d)
+              for name, dims, _, _ in ops}
+    found = []
+    for _, dims, op, operands in ops:
+        shape = tuple(int(d) for d in dims.split(",") if d)
+        if op == "copy" and (shape in stacked or shape in layer):
+            found.append((op, shape))
+        elif op == "dynamic-slice" and shape in layer:
+            found.append((op, shape))
+        elif op == "dynamic-update-slice" and shape in stacked:
+            update = shapes.get(operands.split(",")[1].strip().lstrip("%"))
+            if update is None or int(np.prod(update)) >= layer_size:
+                found.append((op, shape))
+    return found
+
+
+@pytest.mark.parametrize("arch", ["deepseek-7b", "gemma3-27b"])
+@pytest.mark.parametrize("entry", ["decode", "chunk", "verify"])
+def test_step_programs_update_pools_in_place(arch, entry):
+    """The engine's decode, chunk and verify programs, compiled with
+    the cache donated, neither copy a pool nor slice a layer's pool out
+    of the stack and write it back (:func:`_pool_traffic_ops`), and
+    their temporaries stay below one pool's K+V bytes (the pools are
+    sized well above the step's activations)."""
+    cfg = REDUCED[arch]()
+    params, _ = lm.init_lm(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
+    eng = Engine(params, cfg, n_slots=2, max_len=64, eos_id=-1,
+                 paging=PagingConfig(page_size=8, n_pages=512,
+                                     prefill_chunk=16, speculate_k=2))
+    name = {"decode": "step", "chunk": "chunk", "verify": "spec"}[entry]
+    _, fn, args, _ = next(e for e in eng.audit_entry_points()
+                          if e[0] == name)
+    compiled = fn.lower(*args).compile()
+    is_pool = lambda c: isinstance(c, attention.PagedKVCache)  # noqa: E731
+    pools = [p for p in jax.tree.leaves(eng.cache, is_leaf=is_pool)
+             if is_pool(p)]
+    assert pools
+    assert not _pool_traffic_ops(compiled.as_text(), pools)
+    pool_bytes = min(p.k.nbytes + p.v.nbytes for p in pools)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes, (temp, pool_bytes)
 
 
 # ----------------------------------------------------------------------

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import layerwise_paged_step, random_paged_cache
 
 from repro.configs import REDUCED
 from repro.core import quant
@@ -20,8 +21,9 @@ from repro.core.block_traffic import serve_tp_traffic
 from repro.core.types import PagingConfig
 from repro.models import lm
 from repro.serve.engine import Engine, Request
-from repro.serve.placement import (SingleDevice, TensorParallel,
-                                   from_mesh_shape, shard_perm)
+from repro.serve.placement import (CACHE, PARAMS, REP, SingleDevice,
+                                   TensorParallel, from_mesh_shape,
+                                   shard_perm)
 
 # ---------------------------------------------------------------- fast
 
@@ -194,6 +196,58 @@ def test_tp_parity_int8_weights():
     ref, _ = _greedy_streams(qparams, cfg, SingleDevice())
     got, _ = _greedy_streams(qparams, cfg, TensorParallel(4))
     assert got == ref
+
+
+@pytest.mark.slow
+@pytest.mark.dist
+@pytest.mark.parametrize("mode", ["decode", "chunk"])
+def test_tp_carried_pools_match_layerwise_oracle(mode):
+    """2-way TP: inside ``shard_map``, on pools sharded over KV heads,
+    the layer scan that carries the stacked pools matches the
+    layer-by-layer oracle (``conftest.layerwise_paged_step``) run under
+    the same placement, in logits and in every pool."""
+    _need_devices(2)
+    cfg = REDUCED["deepseek-7b"]()
+    key = jax.random.PRNGKey(0)
+    params, _ = lm.init_lm(key, cfg, dtype=jnp.float32)
+    cache, tables = random_paged_cache(cfg, key)
+    tp = TensorParallel(2)
+    tp.validate(cfg)
+    rcfg = tp.compute_cfg(cfg)
+    args = (tp.prepare_params(params, cfg), tp.prepare_cache(cache))
+    if mode == "decode":
+        args += (jnp.asarray([[3], [7]], jnp.int32),
+                 jnp.asarray([5, 37], jnp.int32), tables)
+
+        def new(p, c, t, n, tb):
+            return lm.decode_step(p, c, t, n, rcfg, pages=tb)
+
+        def old(p, c, t, n, tb):
+            return layerwise_paged_step(p, c, t, rcfg, pages=tb,
+                                        lengths=n)
+    else:
+        args += (jax.random.randint(key, (1, 16), 0, cfg.vocab),
+                 jnp.int32(21), jnp.int32(12), tables[1:])
+
+        def new(p, c, t, o, n, tb):
+            return lm.prefill_chunk(p, c, t, rcfg, offset=o, chunk_len=n,
+                                    pages=tb)
+
+        def old(p, c, t, o, n, tb):
+            return layerwise_paged_step(p, c, t, rcfg, pages=tb, offset=o,
+                                        chunk_len=n)
+    kinds = (PARAMS, CACHE) + (REP,) * (len(args) - 2)
+    run = {f: tp.jit(f, kinds=kinds, out_kinds=(REP, CACHE))
+           for f in (new, old)}
+    logits, pools = run[new](*args)
+    want_logits, want_pools = run[old](*args)
+    tol = dict(rtol=1e-5, atol=1e-6)       # as in test_paged's oracle
+    np.testing.assert_allclose(np.asarray(logits),
+                               np.asarray(want_logits), **tol)
+    for got, want in zip(jax.tree.leaves(pools),
+                         jax.tree.leaves(want_pools)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   **tol)
 
 
 @pytest.mark.slow
