@@ -4,11 +4,12 @@ operation, and the breakdown the result line carries.
 The trace is the ``.xplane.pb`` that ``jax.profiler`` writes, read with
 ``jax.profiler.ProfileData``. Device planes are ``/device:TPU:<n>``;
 their ``XLA Ops`` line holds one event per executed operation. Host
-planes carry the benchmark's own spans (``engine.*``, ``bench.*``), on
-the same clock.
+planes carry the program's and the benchmark's own spans (``engine.*``,
+``bench.*``), on the same clock. :func:`read_events` reads the file once
+for :func:`reduce` and ``enginetrace.split`` both.
 
-An op event's name is its HLO instruction text; the trace carries no
-scope metadata and no Pallas kernel names.
+An op event's name is its HLO instruction text; a Pallas kernel's op
+names the kernel in its ``kernel_metadata`` (``enginetrace.kernel_name``).
 
 Operations nest (a ``while`` spans the ops of its body), so busy time is
 a union of intervals, and per-op times sum leaf operations only.
@@ -73,27 +74,35 @@ def op_label(name: str) -> str:
     return f"{op} {shape}"
 
 
-def read_events(path) -> dict:
-    """Device ops (per device) and host spans, in ns."""
-    pd = load(path)
-    devices, host = {}, []
+def read_events(src) -> dict:
+    """Per device, the ops (``XLA Ops``) and program executions (``XLA
+    Modules``); per host thread line, the spans named ``engine.*`` or
+    ``bench.*`` with their arguments; all in ns. ``src`` is a trace
+    directory or file, or what this function returned before."""
+    if isinstance(src, dict):
+        return src
+    pd = load(xplane_path(src))
+    devices, lines = {}, {}
     for plane in pd.planes:
         if DEVICE_PLANE.match(plane.name):
-            ops = []
+            dev = {"modules": [], "ops": []}
             for line in plane.lines:
-                if line.name == "XLA Ops":
-                    for ev in line.events:
-                        ops.append((ev.start_ns, ev.start_ns + ev.duration_ns,
-                                    ev.name))
-            if ops:
-                devices[plane.name] = {"ops": ops}
+                key = {"XLA Modules": "modules",
+                       "XLA Ops": "ops"}.get(line.name)
+                if key:
+                    dev[key] += [(e.start_ns, e.start_ns + e.duration_ns,
+                                  e.name) for e in line.events]
+            if dev["ops"]:
+                devices[plane.name] = dev
         elif plane.name.startswith("/host"):
             for line in plane.lines:
-                for ev in line.events:
-                    if ev.name in HOST_SPANS:
-                        host.append((ev.start_ns, ev.start_ns + ev.duration_ns,
-                                     ev.name))
-    return {"devices": devices, "host": host}
+                spans = [(e.start_ns, e.start_ns + e.duration_ns, e.name,
+                          {k: v for k, v in e.stats})
+                         for e in line.events
+                         if e.name.startswith(("engine.", "bench."))]
+                if spans:
+                    lines[(plane.name, line.name)] = spans
+    return {"devices": devices, "lines": lines}
 
 
 def _gap_cause(mid, host) -> str:
@@ -105,14 +114,17 @@ def _gap_cause(mid, host) -> str:
     return "engine loop: decode dispatch, fetch and bookkeeping"
 
 
-def reduce(trace_dir, wall_s: float) -> dict:
+def reduce(src, wall_s: float) -> dict:
     """Busy time averaged over the devices traced, the span from the
     first device op to the last (a span far below ``wall_s`` means the
-    trace lost events), and the top device ops and idle gaps
-    (seconds)."""
-    ev = read_events(xplane_path(trace_dir))
+    trace lost events), the device seconds of every op label
+    (``op_s``), and the top device ops and idle gaps (seconds). ``src``
+    is what :func:`read_events` reads."""
+    ev = read_events(src)
     if not ev["devices"]:
-        raise ValueError(f"no device operations in the trace {trace_dir}")
+        raise ValueError("no device operations in the trace")
+    host = [sp[:3] for spans in ev["lines"].values() for sp in spans
+            if sp[2] in HOST_SPANS]
     busy, span, op_s, gaps = [], [], {}, []
     for dev in ev["devices"].values():
         merged = _union((s, e) for s, e, _ in dev["ops"])
@@ -129,14 +141,15 @@ def reduce(trace_dir, wall_s: float) -> dict:
     gaps.sort(reverse=True)
     causes = {}
     for dur, mid in gaps:
-        c = _gap_cause(mid, ev["host"])
+        c = _gap_cause(mid, host)
         causes[c] = causes.get(c, 0.0) + dur / 1e9
+    op_s = {k: v / n for k, v in sorted(op_s.items(),
+                                        key=lambda kv: -kv[1])}
     return {
         "busy_s": sum(busy) / n, "window_s": wall_s,
-        "ops_span_s": sum(span) / n,
+        "ops_span_s": sum(span) / n, "op_s": op_s,
         "breakdown": {
-            "device_ops": [[k, v / n] for k, v in sorted(
-                op_s.items(), key=lambda kv: -kv[1])[:TOP]],
+            "device_ops": [[k, v] for k, v in list(op_s.items())[:TOP]],
             "idle_gaps": [[k, v / n] for k, v in sorted(
                 causes.items(), key=lambda kv: -kv[1])[:TOP]]},
     }

@@ -4,7 +4,9 @@
         [--out split.json]
 
 Reads the trace that ``run.py --trace 1 --trace-dir <dir>`` keeps (the
-same file ``devtrace.reduce`` reads) and prints, as JSON:
+same file ``devtrace.reduce`` reads; the harness hands both what
+``devtrace.read_events`` read once, and puts this split into the record
+every metric reader reads) and prints, as JSON:
 
   programs      per engine program on the device's ``XLA Modules``
                 line (``jit_decode_step``, ``jit_prefill_chunk``,
@@ -61,33 +63,6 @@ def kernel_name(op_text: str):
         return None
     m = KERNEL.search(op_text)
     return m.group(1) if m else ""
-
-
-def read(path) -> dict:
-    """Device modules and ops (per device), and host spans (per thread
-    line) named ``engine.*`` or ``bench.*``, in ns."""
-    pd = devtrace.load(devtrace.xplane_path(path))
-    devices, lines = {}, {}
-    for plane in pd.planes:
-        if devtrace.DEVICE_PLANE.match(plane.name):
-            dev = {"modules": [], "ops": []}
-            for line in plane.lines:
-                key = {"XLA Modules": "modules",
-                       "XLA Ops": "ops"}.get(line.name)
-                if key:
-                    dev[key] = [(e.start_ns, e.start_ns + e.duration_ns,
-                                 e.name) for e in line.events]
-            if dev["ops"]:
-                devices[plane.name] = dev
-        elif plane.name.startswith("/host"):
-            for line in plane.lines:
-                spans = [(e.start_ns, e.start_ns + e.duration_ns, e.name,
-                          {k: v for k, v in e.stats})
-                         for e in line.events
-                         if e.name.startswith(("engine.", "bench."))]
-                if spans:
-                    lines[(plane.name, line.name)] = spans
-    return {"devices": devices, "lines": lines}
 
 
 def _overlap(intervals, windows):
@@ -154,10 +129,12 @@ def loop_host_ms(spans):
     return sum(host) / len(host) / 1e6
 
 
-def split(path) -> dict:
-    ev = read(path)
+def split(src) -> dict:
+    """The split of a trace directory or file, or of what
+    ``devtrace.read_events`` read from one."""
+    ev = devtrace.read_events(src)
     if not ev["devices"]:
-        raise ValueError(f"no device operations in the trace {path}")
+        raise ValueError("no device operations in the trace")
     key = _engine_line(ev["lines"])
     spans = [sp for sp in ev["lines"].get(key, [])
              if sp[2].startswith("engine.")] if key else []

@@ -4,12 +4,23 @@ Everything that belongs to one configuration, traffic mix or metric is
 a file of its own, found by the name ``BENCHMARK.json`` gives it:
 
   configs/<config>.json   the model (a registry id, the keys cut from
-                          it, HF-style published keys) and the engine
-                          settings of the deployment it stands for
+                          it, HF-style published keys), the block family
+                          it belongs to and the engine settings of the
+                          deployment it stands for
+  families/<family>.py    one block family: the benchmark's weights of
+                          that layout, the program's parameter tree over
+                          them, the check that file and program agree,
+                          the float32 reference and the work counts
   traffic/<mix>.json      the parameters of one mix; its ``kind`` names
                           the generator module traffic/<kind>.py
   metrics/<metric>.py     one reader per metric: ``read(rec)`` returns
-                          the number, or None where it finds nothing
+                          the number, or None where it finds nothing;
+                          ``rec`` is what :func:`run` builds: the
+                          served rows, the engine's counters at the
+                          window's opening and over it, the booking of
+                          work, the family's work counts and, in a
+                          traced run, the trace split by device op,
+                          program, kernel and engine span
   limits/<cell>.json      the limit of each number compared by the
                           correctness check, with the readings it was
                           set from
@@ -56,6 +67,7 @@ class Cell:
     name: str
     chips: int
     config: dict
+    family: object            # the module families/<family>.py
     mix: dict
     end_to_end: list          # metric entries this cell reports
     per_layer: list
@@ -69,7 +81,12 @@ def load_cell(root: Path, name: str) -> Cell:
         raise KeyError(f"no workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
     cfgs = {c["name"]: c for c in bench["configs"]}
-    config = json.loads((root / cfgs[w["config"]]["file"]).read_text())
+    config_file = cfgs[w["config"]]["file"]
+    config = json.loads((root / config_file).read_text())
+    if "family" not in config:
+        raise KeyError(f"{config_file} names no block family: give it a "
+                       f"'family' key, as families/<family>.py is named")
+    family = load_module(HERE / "families" / f"{config['family']}.py")
     mix = json.loads((HERE / "traffic" / f"{w['traffic']}.json")
                      .read_text())
 
@@ -82,7 +99,8 @@ def load_cell(root: Path, name: str) -> Cell:
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in e2e_names)]
     limits = json.loads((HERE / "limits" / f"{name}.json").read_text())
-    return Cell(name, w["chips"], config, mix, e2e, per_layer, limits)
+    return Cell(name, w["chips"], config, family, mix, e2e, per_layer,
+                limits)
 
 
 def reader(name: str):
@@ -112,22 +130,14 @@ def device_info(chips: int, require_tpu: bool) -> dict:
             "count": len(devs)}
 
 
-def model_config(config: dict):
+def model_config(config: dict, family):
+    """The program's config: the registry entry with the file's
+    overrides, checked by the family against the file's published
+    keys."""
     from repro.configs import get_config
     cfg = dataclasses.replace(get_config(config["registry"]),
                               **config.get("overrides", {}))
-    # the published keys in the file must be what the program runs
-    same = {"hidden_size": cfg.d_model, "intermediate_size": cfg.d_ff,
-            "num_attention_heads": cfg.n_heads,
-            "num_key_value_heads": cfg.n_kv_heads,
-            "head_dim": cfg.head_dim, "vocab_size": cfg.vocab,
-            "num_hidden_layers": cfg.n_layers,
-            "rope_theta": cfg.rope_theta,
-            "tie_word_embeddings": cfg.tie_embeddings}
-    bad = {k: (config[k], v) for k, v in same.items() if config[k] != v}
-    if bad or cfg.act != "silu" or cfg.norm != "rms" \
-            or cfg.family != "dense" or cfg.dtype != "bfloat16":
-        raise ValueError(f"{config['name']}: file and program differ: {bad}")
+    family.check(cfg, config)
     return cfg
 
 
@@ -177,6 +187,27 @@ def warm_up(eng, prompt_range, vocab: int, seed: int) -> dict:
         raise RuntimeError(f"warm-up compiled {counts}, predicted "
                            f"{predicted} for lengths {lens}")
     return counts
+
+
+STEP_PROGRAMS = ("step", "chunk", "spec")
+
+
+def programs_peak_bytes(eng) -> int:
+    """The most device memory one of the engine's step programs (decode,
+    chunked prefill, speculative verify) holds while it runs: arguments
+    + temporaries + outputs - aliased (donated) bytes, from the
+    compiler's memory analysis of each program as the run loop calls
+    it. Warm-up built them with these shapes, so nothing compiles here.
+    The one-shot prefill is left out: its entry point has the smallest
+    bucket, which a cell's traffic need not reach."""
+    peak = 0
+    for name, fn, args, _ in eng.audit_entry_points():
+        if name not in STEP_PROGRAMS:
+            continue
+        ma = fn.lower(*args).compile().memory_analysis()
+        peak = max(peak, ma.argument_size_in_bytes + ma.temp_size_in_bytes
+                   + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    return int(peak)
 
 
 # ----------------------------------------------------------------------
@@ -286,6 +317,20 @@ def request_rows(eng, ctl: Controller) -> list:
     return rows
 
 
+def trace_record(src, wall_s: float, t0: float, t1: float) -> dict:
+    """What a traced window leaves for the readers, from one reading of
+    the trace: ``devtrace.reduce`` (busy time, the breakdown, device
+    seconds per op label) and ``enginetrace.split`` (per program, per
+    Pallas kernel, per engine span, the idle time by span, the decode
+    step and the host loop), with the loop iterations ``t0``/``t1``
+    the trace spans."""
+    import devtrace
+    import enginetrace
+    ev = devtrace.read_events(src)
+    return dict(enginetrace.split(ev), **devtrace.reduce(ev, wall_s),
+                t0=t0, t1=t1)
+
+
 # ----------------------------------------------------------------------
 # correctness
 # ----------------------------------------------------------------------
@@ -309,7 +354,8 @@ def sample_for_check(rows, seed: int, check: dict) -> list:
     return out
 
 
-def reference_gaps(w, arch, prompts, sample, control=None) -> dict:
+def reference_gaps(family, w, arch, prompts, sample,
+                   control=None) -> dict:
     """The widest gap between the reference's best logit and that of
     each served token (``program``); with a control, also that of the
     token the lower-precision reference puts first at each of the same
@@ -321,10 +367,10 @@ def reference_gaps(w, arch, prompts, sample, control=None) -> dict:
         toks = np.asarray(r["tokens"], np.int32)
         seq = np.concatenate([prompt, toks[:-1]])
         pos = np.arange(len(prompt) - 1, len(seq))
-        ref = reference.logits(w, arch, seq, pos)
+        ref = family.logits(w, arch, seq, pos)
         served.append(np.asarray(reference.served_gaps(ref, toks)))
         if control:
-            low = reference.logits(w, arch, seq, pos, mode=control)
+            low = family.logits(w, arch, seq, pos, mode=control)
             ctrl.append(np.asarray(reference.control_gaps(ref, low)))
             del low
         del ref
@@ -353,19 +399,18 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     import jax
     from repro.core import runtime
     import weights
-    import work
     runtime.init_compile_cache()
     compiles = []
     jax.monitoring.register_event_duration_secs_listener(
         lambda ev, dur, **kw: compiles.append(ev)
         if "backend_compile" in ev else None)
 
-    arch = cell.config
-    cfg = model_config(arch)
-    w = weights.make(arch, seed)
+    arch, family = cell.config, cell.family
+    cfg = model_config(arch, family)
+    w = weights.make(family.shapes(arch), seed)
     gen = load_module(HERE / "traffic" / f"{cell.mix['kind']}.py")
     traffic = gen.generate(cell.mix, seed, arch["vocab_size"], seconds)
-    eng = build_engine(cfg, weights.program_params(w), arch["engine"],
+    eng = build_engine(cfg, family.program_params(w, arch), arch["engine"],
                        hook=lambda e: None)
     counts_warm = warm_up(eng, traffic["prompt_range"],
                           arch["vocab_size"], seed)
@@ -398,12 +443,18 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         file=sys.stderr)
     rec = {"cell": cell.name, "seconds": seconds, "setup_s": setup_s,
            "window": (ctl.w0, ctl.w1), "rows": rows, "stats": stats,
+           "stats_open": ctl.snap0,
            "n_slots": eng.n_slots, "page_size": eng.page_size,
-           "booking": eng.booking, "dims": work.dims(arch),
-           "peaks": None, "trace": None}
+           "booking": eng.booking, "work": family,
+           "dims": family.dims(arch), "peaks": None, "trace": None}
     errors, recoveries = list(eng.errors), eng.stats["recoveries"]
     mem = jax.devices()[0].memory_stats() or {}
     device["memory_peak_bytes"] = int(mem.get("peak_bytes_in_use", 0))
+    t_mem = time.perf_counter()
+    device["programs_peak_bytes"] = programs_peak_bytes(eng)
+    log(f"[memory] allocator peak {device['memory_peak_bytes']} B, step "
+        f"programs' peak {device['programs_peak_bytes']} B (compiled in "
+        f"{time.perf_counter() - t_mem:.1f} s)", file=sys.stderr)
 
     # free the program's state before the reference runs
     prompts = {i: r["prompt"] for i, r in enumerate(traffic["requests"])}
@@ -418,13 +469,16 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         rec["peaks"] = peaks["devices"][device["kind"]]
         if keep:
             devtrace.dump(tracer.dir, tracer.dir / "summary.json")
-        rec["trace"] = dict(devtrace.reduce(tracer.dir, tracer.wall),
-                            t0=tracer.t0, t1=tracer.t1)
+        t_read = time.perf_counter()
+        rec["trace"] = trace_record(devtrace.xplane_path(tracer.dir),
+                                    tracer.wall, tracer.t0, tracer.t1)
+        t_read = time.perf_counter() - t_read
         if not keep:
             shutil.rmtree(tracer.dir, ignore_errors=True)
         log(f"[trace] window {tracer.wall:.3f} s, device ops from first "
             f"to last {rec['trace']['ops_span_s']:.3f} s, busy "
-            f"{rec['trace']['busy_s']:.3f} s", file=sys.stderr)
+            f"{rec['trace']['busy_s']:.3f} s; read and split in "
+            f"{t_read:.1f} s", file=sys.stderr)
         device["busy_s"] = rec["trace"]["busy_s"]
         device["window_s"] = rec["trace"]["window_s"]
 
@@ -438,8 +492,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     failed = sum(1 for r in attempted
                  if r["status"] == "failed" or not r["tokens"])
     sample = sample_for_check(rows, seed, cell.mix["check"])
-    gaps = reference_gaps(w, arch, prompts, sample, control) if sample \
-        else {"program": None, "control": None, "tokens": 0}
+    gaps = (reference_gaps(family, w, arch, prompts, sample, control)
+            if sample else {"program": None, "control": None, "tokens": 0})
     log(f"[check] {gaps['tokens']} served tokens of {len(sample)} "
         f"requests compared with the float32 reference: program gap "
         f"{gaps['program']}" + (f", {control} control gap "

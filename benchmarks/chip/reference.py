@@ -1,22 +1,20 @@
-"""Plain float32 reference of a dense llama-style decoder.
+"""The generic pieces of the plain float32 references.
 
-It follows the published architecture of both configurations (pre-norm
-RMSNorm blocks, rotary positions with the half-split rotation, grouped
-query attention, SwiGLU MLP, untied lm_head) from the HF-style keys of
-the configuration file, and imports nothing of the program. It reads
-the benchmark's own weights (``weights.py``) and computes in float32
-with full-precision matmuls, one layer at a time and in blocks of rows,
-so that a 16k-token sequence fits beside the weights on one chip.
+Each block family's module (``families/<family>.py``) builds its own
+reference from these: it follows the published architecture from the
+HF-style keys of the configuration file, imports nothing of the
+program, reads the benchmark's own weights (``weights.py``) and
+computes in float32 with full-precision matmuls, one layer at a time
+and in blocks of rows, so that a 16k-token sequence fits beside the
+weights on one chip.
 
 ``mode`` selects the control, the same forward computed in a lower
 precision than the configuration states: ``"int8"`` (symmetric int8,
 per output channel for weights and per row for activations) or
 ``"fp8"`` (float8 e4m3 with the same scaling), applied to both operands
-of every projection, MLP and lm_head matmul.
+of every matmul that goes through :func:`_mm`.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +24,6 @@ HIGHEST = jax.lax.Precision.HIGHEST
 ROW_BLOCK = 1024     # rows per projection / MLP block
 Q_BLOCK = 256        # query rows per attention block
 SEQ_STEP = 2048      # sequences pad to a multiple of this: few compiles
-LAYER_KEYS = ("attn_norm", "wqkv", "wo", "mlp_norm", "w_gate_up", "w_down")
 
 
 def _fake_quant(x, mode, axis):
@@ -71,20 +68,16 @@ def _by_rows(fn, x):
     return out.reshape(s, -1)
 
 
-@functools.partial(jax.jit, static_argnames=("arch", "mode"))
-def _layer(x, lw, *, arch, mode):
-    hq, hkv, hd, eps, theta = arch
-    s = x.shape[0]
+def _attend(q, k, v):
+    """Causal softmax attention of q (S, Hq, hd) over k and v (S, Hkv,
+    hd), ``Hq / Hkv`` query heads to each kv head, in blocks of
+    ``Q_BLOCK`` query rows; returns (S, Hq hd)."""
+    s, hq, hd = q.shape
+    hkv = k.shape[1]
     g = hq // hkv
     pos = jnp.arange(s)
-    h = _rms(x, lw["attn_norm"], eps)
-    qkv = _by_rows(lambda r: _mm(r, lw["wqkv"], mode), h)
-    q = _rope(qkv[:, :hq * hd].reshape(s, hq, hd), pos, theta)
-    k = _rope(qkv[:, hq * hd:(hq + hkv) * hd].reshape(s, hkv, hd), pos,
-              theta)
-    v = qkv[:, (hq + hkv) * hd:].reshape(s, hkv, hd)
 
-    def attend(i):
+    def block(i):
         qb = jax.lax.dynamic_slice_in_dim(q, i * Q_BLOCK, Q_BLOCK)
         qb = qb.reshape(Q_BLOCK, hkv, g, hd)
         sc = jnp.einsum("qhgd,khd->hgqk", qb, k,
@@ -95,50 +88,16 @@ def _layer(x, lw, *, arch, mode):
         o = jnp.einsum("hgqk,khd->qhgd", p, v, precision=HIGHEST)
         return o.reshape(Q_BLOCK, hq * hd)
 
-    o = jax.lax.map(attend, jnp.arange(s // Q_BLOCK)).reshape(s, hq * hd)
-    x = x + _by_rows(lambda r: _mm(r, lw["wo"], mode), o)
-    h = _rms(x, lw["mlp_norm"], eps)
-
-    def mlp(r):
-        gu = _mm(r, lw["w_gate_up"], mode)
-        f = gu.shape[-1] // 2
-        return _mm(jax.nn.silu(gu[:, :f]) * gu[:, f:], lw["w_down"], mode)
-
-    return x + _by_rows(mlp, h)
+    return jax.lax.map(block, jnp.arange(s // Q_BLOCK)).reshape(s, hq * hd)
 
 
-@functools.partial(jax.jit, static_argnames=("vocab", "eps", "mode"))
-def _head(x, pos, final_norm, lm_head, *, vocab, eps, mode):
-    xs = _rms(x[pos], final_norm, eps)
-    return _mm(xs, lm_head[:, :vocab], mode)
-
-
-def arch_key(arch: dict) -> tuple:
-    return (arch["num_attention_heads"], arch["num_key_value_heads"],
-            arch["head_dim"], float(arch["rms_norm_eps"]),
-            float(arch["rope_theta"]))
-
-
-def logits(w: dict, arch: dict, tokens: np.ndarray, positions: np.ndarray,
-           mode=None) -> jax.Array:
-    """Next-token logits (len(positions), vocab) of the sequence
-    ``tokens`` at ``positions``, in float32 (or in the control's
-    precision). The sequence pads at its end to a multiple of
-    ``SEQ_STEP``; causal attention keeps padding out of every real
-    position."""
+def padded_tokens(tokens: np.ndarray) -> np.ndarray:
+    """The sequence padded at its end to a multiple of ``SEQ_STEP``;
+    causal attention keeps the padding out of every real position."""
     n = len(tokens)
-    s = -(-n // SEQ_STEP) * SEQ_STEP
-    t = np.zeros((s,), np.int32)
+    t = np.zeros((-(-n // SEQ_STEP) * SEQ_STEP,), np.int32)
     t[:n] = tokens
-    key = arch_key(arch)
-    with jax.default_matmul_precision("highest"):
-        x = w["embed"][jnp.asarray(t)].astype(jnp.float32)
-        for layer in range(arch["num_hidden_layers"]):
-            lw = {k: w[k][layer] for k in LAYER_KEYS}
-            x = _layer(x, lw, arch=key, mode=mode)
-        return _head(x, jnp.asarray(positions, jnp.int32), w["final_norm"],
-                     w["lm_head"], vocab=arch["vocab_size"],
-                     eps=float(arch["rms_norm_eps"]), mode=mode)
+    return t
 
 
 @jax.jit

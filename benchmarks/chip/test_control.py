@@ -35,7 +35,8 @@ def root(tmp_path_factory):
     return tiny.make_root(tmp_path_factory.mktemp("checkout"))
 
 
-@pytest.mark.parametrize("cell", ["tiny-batch", "tiny-docqa"])
+@pytest.mark.parametrize("cell", ["tiny-batch", "tiny-docqa",
+                                  "tiny-granite"])
 def test_control_tiny(root, cell):
     prog = run_tiny(root, cell)
     ctrl = run_tiny(root, cell, control="fp8")

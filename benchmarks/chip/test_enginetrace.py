@@ -102,7 +102,7 @@ def test_engine_spans_cover_the_idle_time(split):
 
 
 def test_step_and_loop_figures(split):
-    ev = enginetrace.read(ENGINE)
+    ev = devtrace.read_events(ENGINE)
     (dev,) = ev["devices"].values()
     steps = sorted((e - s) / 1e6 for s, e, name in dev["modules"]
                    if name.startswith("jit_decode_step("))
@@ -161,3 +161,67 @@ def test_overlap_of_intervals_with_windows():
 ])
 def test_kernel_name_from_op_text(text, want):
     assert enginetrace.kernel_name(text) == want
+
+
+# ----------------------------------------------------------------------
+# the record the harness builds from a trace, and the readers of it
+# ----------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def record():
+    import harness
+    return {"trace": harness.trace_record(ENGINE, 1.0, 0.0, 1.0),
+            "stats_open": {"program_builds": 11, "program_build_s": 5.65}}
+
+
+def test_trace_record_reads_the_file_once(monkeypatch):
+    import harness
+    loads = []
+    real = devtrace.load
+    monkeypatch.setattr(devtrace, "load",
+                        lambda p: loads.append(p) or real(p))
+    harness.trace_record(ENGINE, 1.0, 0.0, 1.0)
+    assert len(loads) == 1
+
+
+def test_trace_record_holds_reduce_and_split(record, split):
+    """The breakdown keeps its labels and its top ten; beside it the
+    record carries the whole table of op seconds and the split."""
+    tr = record["trace"]
+    r = devtrace.reduce(ENGINE, wall_s=1.0)
+    assert tr["breakdown"] == r["breakdown"]
+    assert tr["busy_s"] == r["busy_s"] and tr["window_s"] == 1.0
+    assert [[k, v] for k, v in tr["op_s"].items()][:devtrace.TOP] == \
+        r["breakdown"]["device_ops"]
+    assert len(tr["op_s"]) > devtrace.TOP
+    for key in ("programs", "kernels", "program_kernels", "spans",
+                "idle_by_span", "decode_step_ms", "loop_host_ms"):
+        assert tr[key] == split[key], key
+
+
+@pytest.mark.parametrize("metric,key", [
+    ("decode_step_ms", "decode_step_ms"),
+    ("decode_step_ms.docqa", "decode_step_ms"),
+    ("loop_host_ms", "loop_host_ms"),
+    ("loop_host_ms.docqa", "loop_host_ms")])
+def test_trace_readers(record, split, metric, key):
+    """Each reader reads its figure from the record, and reads nothing
+    from an untraced run's."""
+    import harness
+    read = harness.reader(metric)
+    assert read(record) == split[key] > 0
+    assert read({"trace": None}) is None
+
+
+def test_program_build_reader(record):
+    import harness
+    assert harness.reader("program_build_s")(record) == 5.65
+
+
+def test_every_metric_has_a_reader():
+    import json
+    import harness
+    bench = json.loads((HERE.parent.parent / "BENCHMARK.json").read_text())
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        assert callable(harness.reader(m["name"])), m["name"]

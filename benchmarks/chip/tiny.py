@@ -1,9 +1,10 @@
 """A throwaway cell at a tiny size, for the self-tests on the CPU.
 
 :func:`make_root` copies the benchmark into a fresh directory and adds,
-as new files only, a tiny configuration, a tiny mix, a throwaway metric
-and a ``BENCHMARK.json`` that names them. The harness must find all of
-them by name.
+as new files only, tiny configurations, a tiny mix, a throwaway metric,
+a second block family (``testdata/granite.py``, written as
+``families/granite.py``) and a ``BENCHMARK.json`` that names them. The
+harness must find all of them by name.
 """
 from __future__ import annotations
 
@@ -14,7 +15,7 @@ from pathlib import Path
 HERE = Path(__file__).resolve().parent
 
 CONFIG = {
-    "name": "tiny-dense", "registry": "deepseek-7b",
+    "name": "tiny-dense", "registry": "deepseek-7b", "family": "llama",
     "overrides": {"n_layers": 2, "d_model": 64, "n_heads": 4,
                   "n_kv_heads": 2, "head_dim": 16, "d_ff": 96,
                   "vocab": 256},
@@ -26,6 +27,25 @@ CONFIG = {
     "reduced": {}, "chips": 1,
     "engine": {"n_slots": 4, "max_len": 256, "page_size": 8,
                "prefill_chunk": 64, "prefix_cache": True, "eos_id": -1},
+}
+
+# the registry's granite-20b cut to the same tiny size: LayerNorm, a
+# GELU MLP, one kv head and tied embeddings, which the llama family
+# cannot describe. Its keys state what the program runs (rotary
+# positions, eps 1e-6), not the published GPT-BigCode model behind the
+# source (learned positions, eps 1e-5): see testdata/granite.py.
+GRANITE = {
+    "name": "tiny-granite", "registry": "granite-20b", "family": "granite",
+    "overrides": {"n_layers": 2, "d_model": 64, "n_heads": 4,
+                  "n_kv_heads": 1, "head_dim": 16, "d_ff": 256,
+                  "vocab": 256},
+    "source": "https://huggingface.co/ibm-granite/granite-20b-code-base",
+    "hidden_size": 64, "intermediate_size": 256, "num_attention_heads": 4,
+    "num_key_value_heads": 1, "head_dim": 16, "num_hidden_layers": 2,
+    "vocab_size": 256, "hidden_act": "gelu_pytorch_tanh",
+    "layer_norm_epsilon": 1e-06, "rope_theta": 10000.0,
+    "tie_word_embeddings": True, "reduced": {}, "chips": 1,
+    "engine": CONFIG["engine"],
 }
 
 BATCH = {
@@ -47,7 +67,8 @@ DOCQA = {
 }
 
 # the tiny cells' limit on the served-token logit gap: on the CPU the
-# program read 0-0.0078 and the fp8 control 0.126-0.249 (3 seeds each)
+# program read 0-0.0078 and the fp8 control 0.126-0.249 (3 seeds each);
+# in tiny-granite 0.0014-0.0055 and 0.231-0.255 (3 seeds each)
 LIMIT = 0.05
 
 METRIC = '''"""Throwaway metric: requests that produced a token."""
@@ -65,10 +86,14 @@ def make_root(tmp: Path, limit: float = LIMIT) -> Path:
     shutil.copytree(HERE, bench, ignore=shutil.ignore_patterns(
         "__pycache__", "testdata"))
     (bench / "configs" / "tiny-dense.json").write_text(json.dumps(CONFIG))
+    (bench / "configs" / "tiny-granite.json").write_text(
+        json.dumps(GRANITE))
+    shutil.copy(HERE / "testdata" / "granite.py",
+                bench / "families" / "granite.py")
     (bench / "traffic" / "tiny-batch.json").write_text(json.dumps(BATCH))
     (bench / "traffic" / "tiny-docqa.json").write_text(json.dumps(DOCQA))
     (bench / "metrics" / "tiny_requests.py").write_text(METRIC)
-    for cell in ("tiny-batch", "tiny-docqa"):
+    for cell in ("tiny-batch", "tiny-docqa", "tiny-granite"):
         (bench / "limits" / f"{cell}.json").write_text(json.dumps(
             {"served_logit_gap": {"limit": limit}}))
     spec = {
@@ -76,12 +101,17 @@ def make_root(tmp: Path, limit: float = LIMIT) -> Path:
         "paths": ["benchmarks/chip"], "run_seconds": 2,
         "configs": [{"name": "tiny-dense", "source": CONFIG["source"],
                      "file": "benchmarks/chip/configs/tiny-dense.json",
-                     "reduced": [], "why": "self-test"}],
+                     "reduced": [], "why": "self-test"},
+                    {"name": "tiny-granite", "source": GRANITE["source"],
+                     "file": "benchmarks/chip/configs/tiny-granite.json",
+                     "reduced": [], "why": "self-test of a second family"}],
         "workloads": [
             {"name": "tiny-batch", "config": "tiny-dense",
              "traffic": "tiny-batch", "chips": 1, "why": "self-test"},
             {"name": "tiny-docqa", "config": "tiny-dense",
-             "traffic": "tiny-docqa", "chips": 1, "why": "self-test"}],
+             "traffic": "tiny-docqa", "chips": 1, "why": "self-test"},
+            {"name": "tiny-granite", "config": "tiny-granite",
+             "traffic": "tiny-batch", "chips": 1, "why": "self-test"}],
         "end_to_end": [
             {"name": "setup_s", "unit": "s", "better": "lower",
              "bound": 0.25, "source": "host_clock"},
@@ -90,7 +120,7 @@ def make_root(tmp: Path, limit: float = LIMIT) -> Path:
              "workloads": ["tiny-docqa"]},
             {"name": "tiny_requests", "unit": "count", "better": "higher",
              "bound": 0.25, "source": "host_clock",
-             "workloads": ["tiny-batch"]}],
+             "workloads": ["tiny-batch", "tiny-granite"]}],
         "per_layer": [
             {"name": "batch_occupancy", "unit": "%", "better": "higher",
              "source": "program_counter", "layer": "scheduler",

@@ -1,27 +1,28 @@
-"""The work a window needs, from the model's shapes and the engine's
-counts: operations and HBM bytes, whatever implements them.
+"""The work a window needs: operations and HBM bytes, whatever
+implements them, under the keys ``mm_flops``, ``mm_bytes`` (weight
+matmuls), ``attn_flops`` and ``attn_bytes`` (attention over the cache).
+
+Each block family (``families/<family>.py``) gives ``dims(arch)``, the
+sizes below read (``layer_params`` and ``head_params`` are its own),
+and ``decode_step`` and ``prefill_panel``. The counts here fit every
+family that reads each weight once per call and caches K and V of
+``hkv`` heads per layer, and such a family re-exports them; one whose
+work differs (routed experts, a latent cache) counts its own.
 
 What is counted is what the served tokens *need*: prompt tokens
 computed (not prefix-cache hits, not bucket padding), decode slot-steps
 at their live context lengths, each weight read once per program call,
 and live KV pages (not the block table's full width). The utilization
-built on these numbers (and the kernel rooflines, once the trace can
-tell the kernels apart) therefore reads the same work whatever a later
-change does to execute it.
+built on these numbers (and the kernel rooflines) therefore reads the
+same work whatever a later change does to execute it.
 """
 from __future__ import annotations
 
 BF16 = 2
 
 
-def dims(arch: dict) -> dict:
-    d, f = arch["hidden_size"], arch["intermediate_size"]
-    hq, hkv, hd = (arch["num_attention_heads"],
-                   arch["num_key_value_heads"], arch["head_dim"])
-    layer = d * (hq + 2 * hkv) * hd + hq * hd * d + d * 2 * f + f * d
-    return {"d": d, "L": arch["num_hidden_layers"], "hq": hq, "hkv": hkv,
-            "hd": hd, "layer_params": layer, "vocab": arch["vocab_size"],
-            "head_params": d * arch["vocab_size"]}
+def flops(w: dict) -> float:
+    return float(w["mm_flops"] + w["attn_flops"])
 
 
 def _kv_bytes_per_token(m: dict) -> int:
@@ -55,7 +56,3 @@ def prefill_panel(m: dict, tokens: int, prefix: int) -> dict:
     attn_bytes = (prefix + tokens) * _kv_bytes_per_token(m)
     return {"mm_flops": mm_flops, "mm_bytes": mm_bytes,
             "attn_flops": attn_flops, "attn_bytes": attn_bytes}
-
-
-def flops(w: dict) -> float:
-    return float(w["mm_flops"] + w["attn_flops"])
